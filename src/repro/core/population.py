@@ -51,9 +51,13 @@ integer arrays:
 
 Backends: ``numpy`` (default) and ``jax`` (opt-in via
 ``REPRO_POP_ENGINE=jax`` or ``PopulationEvaluator(backend="jax")``), which
-runs the label-propagation inner loop as a jitted kernel and keeps the cost
-gathers in numpy — labels are integers, so the jax path stays bit-identical.
-Set ``REPRO_POP_ENGINE=off`` to force the per-state scalar path.
+runs the label-propagation inner loop as a jitted kernel
+(:func:`label_kernel`) on JAX's default device and keeps the cost gathers in
+numpy — labels are integers, so the jax path stays bit-identical.  A jax
+engine that cannot run raises; it never falls back to numpy.  Its compiled
+programs go to JAX's persistent compilation cache
+(:func:`enable_compile_cache`).  Set ``REPRO_POP_ENGINE=off`` to force the
+per-state scalar path.
 
 Spacemap interaction (``SearchSpec(spacemap=True)``): statically frozen
 genes are masked out *upstream*, in :class:`repro.core.problem.
@@ -66,7 +70,10 @@ edge because no genome ever fuses one.
 """
 from __future__ import annotations
 
+import functools
 import os
+import threading
+from pathlib import Path
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -90,6 +97,25 @@ def engine_mode() -> str:
         raise ValueError(
             f"REPRO_POP_ENGINE={mode!r}; valid: numpy, jax, off")
     return mode
+
+
+#: where the compile cache goes when no directory is configured: a fixed
+#: path in the checkout, so that later processes find what earlier ones wrote
+_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for this process.
+
+    Call before the first compile.  A directory already configured wins:
+    ``JAX_COMPILATION_CACHE_DIR``, which JAX reads itself, or one the caller
+    set; otherwise the cache lives in ``<repo root>/.jax_cache``.  Every
+    compile is kept: the label kernels compile in 0.27-1.51 s on a TPU v5e,
+    mostly under JAX's default 1 s floor for caching."""
+    import jax
+    if jax.config.jax_compilation_cache_dir is None:
+        jax.config.update("jax_compilation_cache_dir", str(_CACHE_DIR))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
 class StaticTables:
@@ -202,9 +228,9 @@ class PopulationEvaluator:
             self.backend = "numpy"
         self._jax_labels = None
         if self.backend == "jax":
-            self._jax_labels = _build_jax_labels(self.t)
-            if self._jax_labels is None:          # jax unavailable: fall back
-                self.backend = "numpy"
+            self._jax_labels = _JaxLabels(self.t)
+        # one batch at a time: island threads share this engine's tables
+        self._lock = threading.Lock()
         # persistent group table (parallel arrays over row ids)
         self._ikeys = np.empty(0, dtype=_I64)     # sorted span-offset keys
         self._irows = np.empty(0, dtype=_I64)     # ... their row ids
@@ -227,25 +253,33 @@ class PopulationEvaluator:
     def fitness_masks(self, masks: Sequence[int], objective: str = "edp"
                       ) -> np.ndarray:
         """Fitness per genome mask (float64 array), canonical order."""
-        t0 = clock.perf_counter()
-        out = self._fitness_masks(masks, objective)
-        self.batch_time += clock.perf_counter() - t0
-        self.batches += 1
-        self.states_scored += len(masks)
+        with self._lock:
+            t0 = clock.perf_counter()
+            out = self._fitness_masks(masks, objective)
+            self.batch_time += clock.perf_counter() - t0
+            self.batches += 1
+            self.states_scored += len(masks)
         return out
 
     def schedulable_masks(self, masks: Sequence[int]) -> np.ndarray:
         """Batched exact schedulability (bool array)."""
-        return self._analyze(masks)[5]
+        with self._lock:
+            return self._analyze(masks)[5]
 
     def group_labels(self, masks: Sequence[int]) -> np.ndarray:
         """(P, n) min-member group label per node (for tests/tools)."""
         return self._labels(self._unpack(masks))[0].reshape(len(masks),
                                                             self.t.n)
 
-    def stats(self) -> Dict[str, float]:
+    def stats(self) -> Dict[str, object]:
+        """Engine counters.  ``device_platform``/``device_kind`` name the
+        device the jax kernel's labels were computed on (None until the
+        first jax batch, and always None on the numpy backend)."""
+        dev = self._jax_labels.device if self._jax_labels else None
         return {
             "backend": self.backend,
+            "device_platform": dev.platform if dev else None,
+            "device_kind": dev.device_kind if dev else None,
             "batches": self.batches,
             "states_scored": self.states_scored,
             "batch_time_s": self.batch_time,
@@ -345,9 +379,8 @@ class PopulationEvaluator:
         """Flat ``(P*n,)`` min-member labels + per-node group max member."""
         if self._jax_labels is not None:
             lf = self._jax_labels(bits)
-            if lf is not None:
-                return lf, self._maxmem(lf, bits.shape[0])
-        lf = self._labels_np(bits)
+        else:
+            lf = self._labels_np(bits)
         return lf, self._maxmem(lf, bits.shape[0])
 
     def _labels_np(self, bits: np.ndarray) -> np.ndarray:
@@ -595,67 +628,93 @@ class PopulationEvaluator:
         return cyc
 
 
-def _build_jax_labels(t: StaticTables):
-    """Jitted label-propagation kernel (the hook/jump inner loop on the jax
-    path); returns None when jax is unavailable.  Integer-only, so results
-    are bit-identical to the numpy path; the caller still verifies
-    idempotence and falls back to numpy if the fixed jump count ever fell
-    short (it cannot for connected hooks, but exactness is non-negotiable)."""
-    try:
-        import jax
-        import jax.numpy as jnp
-    except Exception:                             # pragma: no cover - no jax
-        return None
+def label_tables(t: StaticTables) -> tuple:
+    """The graph arguments of :func:`label_kernel`, as int32 host arrays:
+    node ids, chain-edge nodes and ids, extra-edge endpoints and ids."""
+    return tuple(np.asarray(a, dtype=np.int32) for a in
+                 (t.ar_n, t.chain_nodes, t.chain_eids, t.xu, t.xv,
+                  t.extra_eids))
 
-    n = t.n
-    ar = jnp.asarray(t.ar_n)
-    chain_nodes = jnp.asarray(t.chain_nodes)
-    chain_eids = jnp.asarray(t.chain_eids)
-    xu = jnp.asarray(t.xu)
-    xv = jnp.asarray(t.xv)
-    extra_eids = jnp.asarray(t.extra_eids)
+
+def _labels_jax(bits, ar, chain_nodes, chain_eids, xu, xv, extra_eids):
+    """Label propagation for a ``(P, m)`` 0/1 genome matrix: chain-run
+    labels, then a fixed number of hook-to-min / pointer-jump rounds over
+    the extra edges.  Every shape, and so the compiled program, follows from
+    the argument shapes alone."""
+    import jax
+    import jax.numpy as jnp
+
+    p = bits.shape[0]
+    n = ar.shape[0]
     rounds = int(np.ceil(np.log2(max(n, 2)))) + 2
+    newrun = jnp.ones((p, n), dtype=bool)
+    newrun = newrun.at[:, chain_nodes + 1].set(
+        ~bits[:, chain_eids].astype(bool))
+    lab = jax.lax.cummax(jnp.where(newrun, ar, 0), axis=1)
+    if extra_eids.shape[0]:
+        fused = bits[:, extra_eids].astype(bool)
+        rows = jnp.arange(p)[:, None]
 
-    @jax.jit
-    def kernel(bits):
-        p = bits.shape[0]
-        newrun = jnp.ones((p, n), dtype=bool)
-        newrun = newrun.at[:, chain_nodes + 1].set(
-            ~bits[:, chain_eids].astype(bool))
-        lab = jax.lax.cummax(jnp.where(newrun, ar, 0), axis=1)
-        if extra_eids.size:
-            fused = bits[:, extra_eids].astype(bool)
-            rows = jnp.arange(p)[:, None]
+        def body(lab, _):
+            a = jnp.take_along_axis(lab, jnp.broadcast_to(xu, fused.shape),
+                                    axis=1)
+            b = jnp.take_along_axis(lab, jnp.broadcast_to(xv, fused.shape),
+                                    axis=1)
+            mn = jnp.minimum(a, b)
+            big = jnp.iinfo(lab.dtype).max
+            lab = lab.at[rows, jnp.where(fused, a, 0)].min(
+                jnp.where(fused, mn, big))
+            lab = lab.at[rows, jnp.where(fused, b, 0)].min(
+                jnp.where(fused, mn, big))
+            lab = jnp.take_along_axis(lab, lab, axis=1)   # pointer jump
+            return lab, None
 
-            def body(lab, _):
-                a = jnp.take_along_axis(lab, jnp.broadcast_to(xu, fused.shape),
-                                        axis=1)
-                b = jnp.take_along_axis(lab, jnp.broadcast_to(xv, fused.shape),
-                                        axis=1)
-                mn = jnp.minimum(a, b)
-                big = jnp.iinfo(lab.dtype).max
-                lab = lab.at[rows, jnp.where(fused, a, 0)].min(
-                    jnp.where(fused, mn, big))
-                lab = lab.at[rows, jnp.where(fused, b, 0)].min(
-                    jnp.where(fused, mn, big))
-                lab = jnp.take_along_axis(lab, lab, axis=1)   # pointer jump
-                return lab, None
+        lab, _ = jax.lax.scan(body, lab, None, length=rounds)
+    return jnp.take_along_axis(lab, lab, axis=1)
 
-            lab, _ = jax.lax.scan(body, lab, None, length=rounds)
-        lab = jnp.take_along_axis(lab, lab, axis=1)
-        return lab
 
-    def run(bits: np.ndarray) -> Optional[np.ndarray]:
+@functools.cache
+def label_kernel():
+    """The jitted label kernel, ``(bits, *label_tables(t)) -> (P, n)``
+    labels; built on first use so the numpy path never imports jax."""
+    import jax
+    return jax.jit(_labels_jax)
+
+
+class _JaxLabels:
+    """The jax engine's label pass for one graph: tables placed on the
+    default device once, P padded to a multiple of 16 to bound recompiles.
+    Integer-only, so results are bit-identical to the numpy path; the
+    fixed round count always reaches the fixpoint on connected hooks, and
+    the host checks that it did (raising, not falling back, if not)."""
+
+    def __init__(self, t: StaticTables):
+        try:
+            import jax
+        except ImportError as e:
+            raise ImportError(
+                "the jax population engine was requested "
+                "(REPRO_POP_ENGINE=jax) but jax cannot be imported") from e
+        enable_compile_cache()
+        self.t = t
+        self._kernel = label_kernel()
+        self._tables = jax.device_put(label_tables(t))
+        self.device = None                        # where the labels lived
+
+    def __call__(self, bits: np.ndarray) -> np.ndarray:
+        import jax.numpy as jnp
         p = bits.shape[0]
         pp = -(-p // 16) * 16                     # pad P: bound recompiles
         if pp != p:
             bits = np.concatenate(
                 [bits, np.zeros((pp - p, bits.shape[1]), dtype=bits.dtype)])
-        lab = np.asarray(kernel(jnp.asarray(bits)))[:p].astype(_I64)
-        lf = lab.ravel()
-        rowbase = t.grids(p)[0]
+        out = self._kernel(jnp.asarray(bits), *self._tables)
+        if self.device is None:
+            self.device = next(iter(out.devices()))
+        lf = np.asarray(out)[:p].astype(_I64).ravel()
+        rowbase = self.t.grids(p)[0]
         if not np.array_equal(lf, lf.take(rowbase + lf)):
-            return None                           # paranoid exactness guard
+            raise RuntimeError(
+                "jax label kernel returned labels that are not a fixpoint "
+                f"(graph with n={self.t.n}, P={p})")
         return lf
-
-    return run

@@ -184,6 +184,14 @@ class FusionProblem(SearchProblem):
         elif hasattr(ev, "layerwise"):
             ev.layerwise()
 
+    @property
+    def scores_on_device(self) -> bool:
+        """True once this problem's population engine is the jax one: the
+        process holds the device, so ``repro.search.island`` keeps its
+        islands in-process rather than forking children that would need it."""
+        pop = getattr(self.evaluator, "_pop", None)
+        return pop is not None and pop.backend == "jax"
+
     def fitness(self, genome: FusionState) -> float:
         return float(self.evaluator.fitness(genome, self.objective))
 

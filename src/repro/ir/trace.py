@@ -22,7 +22,7 @@ Layer` kinds:
 Everything elementwise or shape-plumbing (relu via ``max(x, 0)``, bias
 adds, activations, reshape/transpose/broadcast, dtype casts) is *folded*
 into its producer — those ops move no DRAM traffic the fusion cost model
-accounts separately.  ``pjit`` / ``custom_jvp_call`` bodies are walked
+accounts separately.  ``jit`` / ``custom_jvp_call`` bodies are walked
 recursively, so ``jax.jit``- or ``jax.nn``-wrapped models trace the same
 as raw ``lax`` code.
 
@@ -78,6 +78,9 @@ _MUL_PRIMS = frozenset({"mul", "div"})
 _WINDOW_PRIMS = frozenset({"reduce_window_max", "reduce_window_sum",
                            "reduce_window_min"})
 _REDUCE_PRIMS = frozenset({"reduce_sum", "reduce_max", "reduce_min"})
+#: call-like primitives whose inner jaxpr is walked in place (jax 0.9 names)
+_CALL_PRIMS = frozenset({"jit", "closed_call", "custom_jvp_call",
+                         "custom_vjp_call", "remat2"})
 
 
 @dataclass
@@ -175,9 +178,7 @@ class _Walker:
             return self._binary(eqn, "add" if prim in _ADD_PRIMS else "mul")
         if prim == "concatenate":
             return self._concat(eqn)
-        if prim in ("pjit", "closed_call", "core_call", "xla_call",
-                    "custom_jvp_call", "custom_vjp_call", "checkpoint",
-                    "remat"):
+        if prim in _CALL_PRIMS:
             return self._call(eqn)
         if prim in _ALIAS_PRIMS:
             return self._alias(eqn)

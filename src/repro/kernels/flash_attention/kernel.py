@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 NEG_INF = -2.0 ** 30
 
@@ -125,7 +123,7 @@ def flash_attention_kernel(q, k, v, *, causal=True, window=0, chunk=0,
             pltpu.VMEM((block_q,), jnp.float32),      # l
             pltpu.VMEM((block_q, D), jnp.float32),    # acc
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

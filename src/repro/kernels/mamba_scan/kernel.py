@@ -22,9 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
-
 
 def _scan_kernel(da_ref, dbx_ref, c_ref, y_ref, h_scr, *, ts):
     ti = pl.program_id(2)
@@ -73,7 +70,7 @@ def mamba_scan_kernel(da, dbx, c, *, block_d=128, time_chunk=128,
                                lambda b, d, t: (b, t, d)),
         out_shape=jax.ShapeDtypeStruct((B, S, Di), da.dtype),
         scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(da, dbx, c)

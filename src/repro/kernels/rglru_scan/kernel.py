@@ -14,9 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
-
 
 def _rglru_kernel(a_ref, b_ref, h_ref, h_scr, *, ts):
     ti = pl.program_id(2)
@@ -55,7 +52,7 @@ def rglru_scan_kernel(a, b, *, block_w=128, time_chunk=256, interpret=False):
                                lambda b_, w, t: (b_, t, w)),
         out_shape=jax.ShapeDtypeStruct((B, S, W), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -12,9 +12,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
-
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps):
     x = x_ref[...].astype(jnp.float32)
@@ -50,7 +47,7 @@ def rmsnorm_kernel(x, w, residual=None, *, eps=1e-6, block_rows=256,
             functools.partial(_rmsnorm_kernel, eps=eps),
             grid=grid, in_specs=[row_spec, w_spec], out_specs=row_spec,
             out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             interpret=interpret)(x, w)
         return out[:N]
@@ -60,7 +57,7 @@ def rmsnorm_kernel(x, w, residual=None, *, eps=1e-6, block_rows=256,
         out_specs=(row_spec, row_spec),
         out_shape=(jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct(x.shape, x.dtype)),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret)(x, residual, w)
     return out[:N], res[:N]

@@ -14,14 +14,15 @@ bit-identical (pinned by ``tests/test_island.py``).
 Workers default to ``multiprocessing`` with the ``fork`` start method (the
 search problem and its evaluator caches are inherited copy-on-write; only
 integer genome masks and fitness floats cross process boundaries, via
-``SearchProblem.encode_genome``/``decode_genome``).  Where ``fork`` is
-unavailable — or this process may not fork (daemonic pool workers, e.g.
-inside a ``BatchScheduler`` search worker) — the backend falls back to
-threads: identical semantics and results, no parallel speedup.  Note that
-forking a process that has already imported jax draws jax's
-multithreading warning; island children run only the stdlib search stack
-(graph/fusion/cost model) and never call into jax, so the fusion-search
-path is unaffected.
+``SearchProblem.encode_genome``/``decode_genome``).  The backend runs its
+islands as threads in this process instead — identical semantics and
+results, no parallel speedup — where:
+
+* the problem scores on a device (the jax population engine,
+  ``scores_on_device``): the process that placed the kernel's tables owns
+  the device, and a forked child that scored would need it too;
+* ``fork`` is unavailable, or this process may not fork (daemonic pool
+  workers, e.g. inside a ``BatchScheduler`` search worker).
 
 Session budget/patience apply at sync barriers: the parent aggregates
 island stats there and broadcasts stop.  Barriers happen every
@@ -206,7 +207,8 @@ class IslandBackend(SearchBackend):
     ``migrate_every`` (generations between elite exchanges, default 20),
     ``migrants`` (elites shipped around the ring per exchange, default 2),
     ``workers`` (``"process"`` | ``"thread"``, default ``"process"`` with a
-    thread fallback where fork is unavailable) — plus every ``ga`` backend
+    thread fallback where fork is unavailable or the problem scores on a
+    device) — plus every ``ga`` backend
     key (``preset``, ``generations``, ``population``, ``top_n``,
     ``mutations_per_gen``, ``random_survivors``, ``crossover_rate``,
     ``ga_config``), which configures each island identically.  Island ``i``
@@ -251,13 +253,15 @@ class IslandBackend(SearchBackend):
         sync_gens = _sync_gens(configs[0].generations, migrate_every)
         migration_gens = [g for g in sync_gens
                           if (g + 1) % migrate_every == 0]
-        ctx = _fork_context() if workers == "process" else None
         # build every read-only shared structure BEFORE forking so workers
         # inherit the compiled graph, baseline costs, and population-engine
         # tables copy-on-write instead of each rebuilding them
         prewarm = getattr(problem, "prewarm", None)
         if prewarm is not None:
             prewarm()
+        if getattr(problem, "scores_on_device", False):
+            workers = "thread"              # one process per device
+        ctx = _fork_context() if workers == "process" else None
         chans, workers_alive = self._spawn(problem, configs, sync_gens,
                                            migration_gens, migrants, ctx)
         try:

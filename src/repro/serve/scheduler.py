@@ -10,8 +10,10 @@ requests and resolves each one the cheapest way available:
    already in the :class:`~repro.serve.store.ArtifactStore` is served
    from disk with *zero* new evaluations (no evaluator is even built);
 3. **search** — remaining unique misses fan out across a worker pool
-   (``multiprocessing`` fork workers; inline when ``workers <= 1``) and
-   their artifacts are stored for every later identical request.
+   (``multiprocessing`` fork workers; inline when ``workers <= 1``, and
+   always inline under the jax population engine, whose process holds the
+   device a forked worker would need) and their artifacts are stored for
+   every later identical request.
 
 The CLI speaks this layer: ``repro serve --requests jobs.json`` drains a
 batch, ``repro submit`` is the single-request path.
@@ -109,6 +111,16 @@ def _search_worker(spec_dict: Dict) -> tuple:
         return ("ok", artifact.to_dict())
     except Exception as e:                       # noqa: BLE001 — job isolation
         return ("err", f"{type(e).__name__}: {e}")
+
+
+def _engine_on_device() -> bool:
+    """True under the jax population engine: this process holds the device
+    that a forked search worker would need."""
+    try:
+        from repro.core.population import engine_mode
+    except ImportError:                          # no numpy: scalar path only
+        return False
+    return engine_mode() == "jax"
 
 
 class BatchScheduler:
@@ -233,7 +245,8 @@ class BatchScheduler:
             self._serve(job, artifact, "searched", put=True)
 
     def _map_searches(self, spec_dicts: List[Dict]) -> List[tuple]:
-        if self.workers <= 1 or len(spec_dicts) == 1:
+        if (self.workers <= 1 or len(spec_dicts) == 1
+                or _engine_on_device()):          # one process per device
             return [_search_worker(d) for d in spec_dicts]
         import multiprocessing
         try:

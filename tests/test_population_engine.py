@@ -256,7 +256,6 @@ def test_engine_mode_off_env(monkeypatch):
 
 
 def test_jax_backend_labels_bit_identical():
-    pytest.importorskip("jax")
     graph = mobilenet_v3_large()
     m = graph.compiled().m
     rng = random.Random(9)
@@ -265,10 +264,82 @@ def test_jax_backend_labels_bit_identical():
     pe_np = ev_np.population(backend="numpy")
     ev_jx = Evaluator(graph, SIMBA)
     pe_jx = ev_jx.population(backend="jax")
-    if pe_jx.backend != "jax":
-        pytest.skip("jax backend unavailable at runtime")
+    assert pe_jx.backend == "jax"
     assert np.array_equal(pe_jx.group_labels(masks), pe_np.group_labels(masks))
     for obj in OBJECTIVES:
         a = pe_jx.fitness_masks(masks, obj)
         b = pe_np.fitness_masks(masks, obj)
         assert np.array_equal(a, b)
+    import jax
+    dev = jax.devices()[0]
+    st = pe_jx.stats()
+    assert (st["device_platform"], st["device_kind"]) == \
+        (dev.platform, dev.device_kind)
+    assert pe_np.stats()["device_platform"] is None
+
+
+# ---- the jax engine never falls back to numpy -------------------------------------
+def test_jax_engine_without_jax_raises(monkeypatch):
+    import sys
+    monkeypatch.setitem(sys.modules, "jax", None)     # import jax -> error
+    ev = Evaluator(mobilenet_v3_large(), SIMBA)
+    with pytest.raises(ImportError, match="REPRO_POP_ENGINE=jax"):
+        ev.population(backend="jax")
+
+
+def test_jax_idempotence_guard_miss_raises():
+    import jax.numpy as jnp
+    graph = mobilenet_v3_large()
+    n, m = graph.compiled().n, graph.compiled().m
+    pe = Evaluator(graph, SIMBA).population(backend="jax")
+
+    def shifted(bits, *tables):       # node j -> j-1: not a fixpoint
+        lab = jnp.maximum(jnp.arange(n) - 1, 0)
+        return jnp.broadcast_to(lab, (bits.shape[0], n))
+
+    pe._jax_labels._kernel = shifted
+    rng = random.Random(2)
+    with pytest.raises(RuntimeError, match="not a fixpoint"):
+        pe.fitness_masks([rng.getrandbits(m) for _ in range(MIN_BATCH)],
+                         "edp")
+
+
+# ---- one process per device ---------------------------------------------------------
+def _no_fork(*_a, **_k):
+    raise AssertionError("a fork context was requested under the jax engine")
+
+
+def test_island_jax_engine_runs_in_process_and_matches_numpy(monkeypatch):
+    import repro.search.island as island
+    from repro.search import search
+    cfg = {"preset": "fast", "generations": 6, "islands": 2,
+           "migrate_every": 3}
+    monkeypatch.setenv("REPRO_POP_ENGINE", "numpy")
+    ref = search("vgg16", "simba", backend="island", seed=3,
+                 backend_config=cfg)
+    monkeypatch.setenv("REPRO_POP_ENGINE", "jax")
+    monkeypatch.setattr(island, "_fork_context", _no_fork)
+    got = search("vgg16", "simba", backend="island", seed=3,
+                 backend_config=cfg)
+    assert got.backend_stats["pop_backend"] == "jax"
+    assert ref.backend_stats["pop_backend"] == "numpy"
+    assert got.genome_mask == ref.genome_mask
+    assert got.best_fitness == ref.best_fitness
+    assert got.history == ref.history
+
+
+def test_batch_scheduler_jax_engine_runs_misses_in_process(monkeypatch,
+                                                            tmp_path):
+    import multiprocessing
+    from repro.search import SearchSpec
+    from repro.serve import ArtifactStore, BatchScheduler
+    monkeypatch.setenv("REPRO_POP_ENGINE", "jax")
+    monkeypatch.setattr(multiprocessing, "get_context", _no_fork)
+    sched = BatchScheduler(ArtifactStore(str(tmp_path)), workers=2)
+    for wl in ("vgg16", "unet"):
+        sched.submit(SearchSpec(workload=wl, backend="ga", backend_config={
+            "preset": "fast", "generations": 2}))
+    out = sched.run()
+    assert out.stats["searched"] == 2 and out.stats["failed"] == 0
+    for job in out.jobs:
+        assert job.artifact.backend_stats["pop_backend"] == "jax"
