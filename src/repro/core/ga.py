@@ -40,6 +40,8 @@ from operator import itemgetter
 
 from repro.core.graph import LayerGraph
 from repro.core.problem import FusionProblem, SearchProblem
+from repro.obs import Phases
+from repro.obs.phase import PhaseSnapshot
 
 _first = itemgetter(0)
 
@@ -82,6 +84,9 @@ class GAResult:
     history: List[float] = field(default_factory=list)   # best fitness per gen
     evaluations: int = 0              # unique genomes scored
     offspring_evaluated: int = 0      # offspring submitted for scoring
+    # the loop's ``ga.*`` phase spans (repro.obs.Phases snapshot); timing
+    # only, so two results compare equal without it
+    phases: PhaseSnapshot = field(default_factory=dict, compare=False)
 
     @property
     def generations_run(self) -> int:
@@ -141,6 +146,12 @@ def run_ga_problem(problem: SearchProblem, config: GAConfig = GAConfig(),
     each generation — this is the island-model elite-exchange hook
     (``repro.search.island``); with ``migrate=None`` the loop's behavior is
     bit-for-bit that of earlier revisions.
+
+    The result's ``phases`` time the loop: ``ga.generation`` (one loop
+    body), ``ga.mutate`` (the mutation and the top-up loops), ``ga.score``
+    (genome keys and the run-level cache, outside the problem's batch
+    scorer), ``ga.select`` (:func:`select_pool`) and ``ga.observe`` (the
+    observer call).
     """
     rng = random.Random(config.seed)
     # bound locals for the per-offspring hot path; getrandbits drives an
@@ -151,23 +162,28 @@ def run_ga_problem(problem: SearchProblem, config: GAConfig = GAConfig(),
     pbatch_unique = getattr(problem, "fitness_batch_unique", None)
     fit_cache: Dict[Hashable, float] = {}
     offspring_evaluated = 0
+    phases = Phases()
+    span = phases.span
 
     def score(states: List) -> List[float]:
         """Fitness per genome, via the run-level cache; novel genomes are
         scored in one batch so the evaluator can dedupe shared structure.
         The fresh list is unique by construction, so problems exposing
         ``fitness_batch_unique`` skip their own dedup pass."""
-        keys = [pkey(s) for s in states]
-        fresh: Dict[Hashable, object] = {}
-        for k, s in zip(keys, states):
-            if k not in fit_cache and k not in fresh:
-                fresh[k] = s
-        if fresh:
+        with span("ga.score"):
+            keys = [pkey(s) for s in states]
+            fresh: Dict[Hashable, object] = {}
+            for k, s in zip(keys, states):
+                if k not in fit_cache and k not in fresh:
+                    fresh[k] = s
             vals = list(fresh.values())
+        if vals:
             fits = (pbatch_unique(vals) if pbatch_unique is not None
                     else problem.fitness_batch(vals))
-            fit_cache.update(zip(fresh, fits))
-        return [fit_cache[k] for k in keys]
+        with span("ga.score"):
+            if vals:
+                fit_cache.update(zip(fresh, fits))
+            return [fit_cache[k] for k in keys]
 
     # warm-start seeding (repro.serve.warmstart): extra genomes scored into
     # the initial pool alongside the canonical start.  With no seeds (the
@@ -186,53 +202,62 @@ def run_ga_problem(problem: SearchProblem, config: GAConfig = GAConfig(),
     history: List[float] = []
 
     for gen in range(config.generations):
-        offspring: List = []
-        npool = len(pool)
-        kbits = npool.bit_length()
-        for _ in range(config.mutations_per_gen):
-            r = getrandbits(kbits)
-            while r >= npool:
-                r = getrandbits(kbits)
-            parent = pool[r][1]
-            if config.crossover_rate and rng.random() < config.crossover_rate \
-                    and len(pool) > 1:
-                other = pool[rng.randrange(len(pool))][1]
-                parent = problem.crossover(parent, other, rng)
-            offspring.append(pmut(parent, rng))
-        fits = score(offspring)
-        offspring_evaluated += len(offspring)
+        with span("ga.generation"):
+            with span("ga.mutate"):
+                offspring: List = []
+                npool = len(pool)
+                kbits = npool.bit_length()
+                for _ in range(config.mutations_per_gen):
+                    r = getrandbits(kbits)
+                    while r >= npool:
+                        r = getrandbits(kbits)
+                    parent = pool[r][1]
+                    if config.crossover_rate \
+                            and rng.random() < config.crossover_rate \
+                            and len(pool) > 1:
+                        other = pool[rng.randrange(len(pool))][1]
+                        parent = problem.crossover(parent, other, rng)
+                    offspring.append(pmut(parent, rng))
+            fits = score(offspring)
+            offspring_evaluated += len(offspring)
 
-        pool = select_pool(pool + list(zip(fits, offspring)),
-                           config.top_n, config.random_survivors, rng,
-                           key=problem.key)
-        # keep the pool topped up to the paper's full P with fresh mutants of
-        # survivors (duplicates allowed; next generation dedupes); parents are
-        # picked by size-2 tournament over the rank-sorted survivor list, which
-        # balances intensification around the elite against survivor diversity
-        if len(pool) < config.population:
-            need = config.population - len(pool)
-            n_surv = len(pool)
-            sbits = n_surv.bit_length()
-            topup = []
-            for _ in range(need):
-                i = getrandbits(sbits)
-                while i >= n_surv:
-                    i = getrandbits(sbits)
-                j = getrandbits(sbits)
-                while j >= n_surv:
-                    j = getrandbits(sbits)
-                topup.append(pmut(pool[i if i < j else j][1], rng))
-            tfits = score(topup)
-            offspring_evaluated += len(topup)
-            pool.extend(zip(tfits, topup))
-        if migrate is not None:
-            migrated = migrate(gen, pool)
-            if migrated is not None:
-                pool = migrated
-        history.append(max(f for f, _ in pool))
-        if observer is not None and observer(gen, history[-1], len(fit_cache),
-                                             offspring_evaluated):
-            break
+            with span("ga.select"):
+                pool = select_pool(pool + list(zip(fits, offspring)),
+                                   config.top_n, config.random_survivors,
+                                   rng, key=problem.key)
+            # keep the pool topped up to the paper's full P with fresh
+            # mutants of survivors (duplicates allowed; next generation
+            # dedupes); parents are picked by size-2 tournament over the
+            # rank-sorted survivor list, which balances intensification
+            # around the elite against survivor diversity
+            if len(pool) < config.population:
+                with span("ga.mutate"):
+                    need = config.population - len(pool)
+                    n_surv = len(pool)
+                    sbits = n_surv.bit_length()
+                    topup = []
+                    for _ in range(need):
+                        i = getrandbits(sbits)
+                        while i >= n_surv:
+                            i = getrandbits(sbits)
+                        j = getrandbits(sbits)
+                        while j >= n_surv:
+                            j = getrandbits(sbits)
+                        topup.append(pmut(pool[i if i < j else j][1], rng))
+                tfits = score(topup)
+                offspring_evaluated += len(topup)
+                pool.extend(zip(tfits, topup))
+            if migrate is not None:
+                migrated = migrate(gen, pool)
+                if migrated is not None:
+                    pool = migrated
+            history.append(max(f for f, _ in pool))
+            if observer is not None:
+                with span("ga.observe"):
+                    stop = observer(gen, history[-1], len(fit_cache),
+                                    offspring_evaluated)
+                if stop:
+                    break
 
     best_f, best_s = max(pool, key=lambda fs: fs[0])
     # batch scoring may re-associate float sums (~1 ulp); report the winner's
@@ -240,7 +265,8 @@ def run_ga_problem(problem: SearchProblem, config: GAConfig = GAConfig(),
     best_f = problem.fitness(best_s)
     return GAResult(best_state=best_s, best_fitness=best_f,
                     history=history, evaluations=len(fit_cache),
-                    offspring_evaluated=offspring_evaluated)
+                    offspring_evaluated=offspring_evaluated,
+                    phases=phases.snapshot())
 
 
 def run_ga(graph: LayerGraph, evaluator, config: GAConfig = GAConfig(),

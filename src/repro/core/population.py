@@ -78,7 +78,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from repro.obs import clock
+from repro.obs import Phases
 
 _MISSING = object()
 
@@ -220,15 +220,18 @@ class PopulationEvaluator:
     canonical scalar path (pinned by ``tests/test_population_engine.py``).
     """
 
-    def __init__(self, evaluator, backend: Optional[str] = None):
+    def __init__(self, evaluator, backend: Optional[str] = None,
+                 phases: Optional[Phases] = None):
         self.ev = evaluator
+        #: named spans of each batch's phases (``pop.*``), see stats()
+        self.phases = phases if phases is not None else Phases()
         self.t = StaticTables(evaluator.cg)
         self.backend = backend or engine_mode()
         if self.backend == "off":
             self.backend = "numpy"
         self._jax_labels = None
         if self.backend == "jax":
-            self._jax_labels = _JaxLabels(self.t)
+            self._jax_labels = _JaxLabels(self.t, self.phases)
         # one batch at a time: island threads share this engine's tables
         self._lock = threading.Lock()
         # persistent group table (parallel arrays over row ids)
@@ -244,8 +247,6 @@ class PopulationEvaluator:
         self._lowsb = np.empty(0, dtype=np.float64)
         self._gmasks: List[int] = []              # row id -> member bitmask
         self._pending: List[tuple] = []           # rows awaiting commit
-        self.batch_time = 0.0                     # seconds inside the engine
-        self.batches = 0
         self.states_scored = 0
         self.residue_checks = 0                   # exact pair-closure runs
 
@@ -254,10 +255,8 @@ class PopulationEvaluator:
                       ) -> np.ndarray:
         """Fitness per genome mask (float64 array), canonical order."""
         with self._lock:
-            t0 = clock.perf_counter()
-            out = self._fitness_masks(masks, objective)
-            self.batch_time += clock.perf_counter() - t0
-            self.batches += 1
+            with self.phases.span("pop.batch"):
+                out = self._fitness_masks(masks, objective)
             self.states_scored += len(masks)
         return out
 
@@ -268,49 +267,78 @@ class PopulationEvaluator:
 
     def group_labels(self, masks: Sequence[int]) -> np.ndarray:
         """(P, n) min-member group label per node (for tests/tools)."""
-        return self._labels(self._unpack(masks))[0].reshape(len(masks),
-                                                            self.t.n)
+        with self._lock:
+            lf = self._labels(self._unpack(masks))[0]
+        return lf.reshape(len(masks), self.t.n)
 
     def stats(self) -> Dict[str, object]:
         """Engine counters.  ``device_platform``/``device_kind`` name the
         device the jax kernel's labels were computed on (None until the
-        first jax batch, and always None on the numpy backend)."""
+        first jax batch, and always None on the numpy backend).
+        ``batches`` and ``batch_time_s`` are the ``pop.batch`` span's calls
+        and seconds; ``phases`` holds every ``pop.*`` span:
+
+        * ``pop.build`` — this engine's construction by
+          ``Evaluator.population()``: the layerwise baseline, the static
+          tables and (jax) placing them on the device;
+        * ``pop.batch`` — one :meth:`fitness_masks` call, lock held;
+        * ``pop.unpack`` — mask ints to the ``(P, m)`` bit matrix;
+        * ``pop.labels.launch`` — padding, host-to-device copy and dispatch
+          of the label kernel; ``pop.labels.wait`` — reading its labels
+          back (the device time still running, then device-to-host);
+          ``pop.labels.check`` — int64 conversion and the fixpoint check;
+          ``pop.labels.host`` — the numpy backend's label pass;
+        * ``pop.maxmem`` — each node's group maximum member;
+        * ``pop.rows`` — group slots, keys and table lookup/insert;
+        * ``pop.sched`` — per-genome flags and the exact residue check;
+        * ``pop.cost`` — cost-model runs for novel groups;
+        * ``pop.gather`` — validity and the fitness bincounts."""
         dev = self._jax_labels.device if self._jax_labels else None
+        batch_time = self.phases.seconds("pop.batch")
         return {
             "backend": self.backend,
             "device_platform": dev.platform if dev else None,
             "device_kind": dev.device_kind if dev else None,
-            "batches": self.batches,
+            "batches": self.phases.calls("pop.batch"),
             "states_scored": self.states_scored,
-            "batch_time_s": self.batch_time,
-            "batch_evals_per_sec": (self.states_scored / self.batch_time
-                                    if self.batch_time else 0.0),
+            "batch_time_s": batch_time,
+            "batch_evals_per_sec": (self.states_scored / batch_time
+                                    if batch_time else 0.0),
             "group_table_rows": len(self._gmasks),
             "residue_checks": self.residue_checks,
+            "phases": self.phases.snapshot(),
         }
 
     # ---- batch pipeline -------------------------------------------------------------
     def _unpack(self, masks: Sequence[int]) -> np.ndarray:
         t = self.t
         nb = t.mask_bytes
-        buf = b"".join(mk.to_bytes(nb, "little") for mk in masks)
-        raw = np.frombuffer(buf, dtype=np.uint8).reshape(len(masks), nb)
-        return np.unpackbits(raw, axis=1, bitorder="little")[:, :t.m]
+        with self.phases.span("pop.unpack"):
+            buf = b"".join(mk.to_bytes(nb, "little") for mk in masks)
+            raw = np.frombuffer(buf, dtype=np.uint8).reshape(len(masks), nb)
+            return np.unpackbits(raw, axis=1, bitorder="little")[:, :t.m]
 
     def _analyze(self, masks: Sequence[int]) -> tuple:
         """Shared front half: labels, group slots, table rows, and exact
         per-genome schedulability — no cost-model work."""
         t = self.t
         p, n = len(masks), t.n
+        span = self.phases.span
         bits = self._unpack(masks)
         lf, mx = self._labels(bits)
         rowbase, ar_flat = t.grids(p)
-        # one slot per multi-member group: its min member ("label") node
-        slot_mask = (lf == ar_flat) & (mx > ar_flat)
-        gslots = np.nonzero(slot_mask)[0]         # ascending (genome, label)
-        gp = gslots // n
-        if gslots.size:
-            rows = self._rows_for_slots(lf, mx, gslots)
+        with span("pop.rows"):
+            # one slot per multi-member group: its min member ("label") node
+            slot_mask = (lf == ar_flat) & (mx > ar_flat)
+            gslots = np.nonzero(slot_mask)[0]     # ascending (genome, label)
+            gp = gslots // n
+            if gslots.size:
+                rows = self._rows_for_slots(lf, mx, gslots)
+            else:
+                rows = np.empty(0, dtype=_I64)
+        if not gslots.size:
+            return lf, mx, gslots, gp, rows, np.ones(p, dtype=bool)
+        with span("pop.sched"):
             flags = np.bincount(gp, weights=self._lowsb.take(rows),
                                 minlength=p).astype(_I64)
             unsched = (flags >> np.int64(32)) > 0
@@ -321,23 +349,29 @@ class PopulationEvaluator:
                 cyc = self._sched_exact(lf.reshape(p, n)[residue],
                                         mx.reshape(p, n)[residue])
                 unsched[residue] |= cyc
-        else:
-            rows = np.empty(0, dtype=_I64)
-            unsched = np.zeros(p, dtype=bool)
         return lf, mx, gslots, gp, rows, ~unsched
 
     def _fitness_masks(self, masks, objective) -> np.ndarray:
-        ev = self.ev
-        base = ev._ensure_base()
-        p = len(masks)
+        base = self.ev._ensure_base()
         _, _, gslots, gp, rows, ok = self._analyze(masks)
         # cost-model work only for schedulable genomes' novel groups,
         # mirroring the scalar path's laziness
+        keep = None
         if rows.size:
             keep = ok.take(gp)
             need = rows[keep & ~self._costed.take(rows)]
             if need.size:
-                self._cost_rows(need)
+                with self.phases.span("pop.cost"):
+                    self._cost_rows(need)
+        with self.phases.span("pop.gather"):
+            return self._gather(base, gp, rows, ok, keep, objective)
+
+    def _gather(self, base, gp, rows, ok, keep, objective) -> np.ndarray:
+        """Fitness per genome: the base sums plus the corrections of the
+        groups of schedulable genomes (``keep``: which group slots those
+        are; None when no genome has a multi-member group)."""
+        p = ok.size
+        if keep is not None:
             gp = gp[keep]
             rows = rows[keep]
             bad = np.bincount(gp, weights=~self._tvalid.take(rows),
@@ -380,8 +414,10 @@ class PopulationEvaluator:
         if self._jax_labels is not None:
             lf = self._jax_labels(bits)
         else:
-            lf = self._labels_np(bits)
-        return lf, self._maxmem(lf, bits.shape[0])
+            with self.phases.span("pop.labels.host"):
+                lf = self._labels_np(bits)
+        with self.phases.span("pop.maxmem"):
+            return lf, self._maxmem(lf, bits.shape[0])
 
     def _labels_np(self, bits: np.ndarray) -> np.ndarray:
         t = self.t
@@ -688,7 +724,7 @@ class _JaxLabels:
     fixed round count always reaches the fixpoint on connected hooks, and
     the host checks that it did (raising, not falling back, if not)."""
 
-    def __init__(self, t: StaticTables):
+    def __init__(self, t: StaticTables, phases: Phases):
         try:
             import jax
         except ImportError as e:
@@ -697,24 +733,31 @@ class _JaxLabels:
                 "(REPRO_POP_ENGINE=jax) but jax cannot be imported") from e
         enable_compile_cache()
         self.t = t
+        self.phases = phases
         self._kernel = label_kernel()
         self._tables = jax.device_put(label_tables(t))
         self.device = None                        # where the labels lived
 
     def __call__(self, bits: np.ndarray) -> np.ndarray:
         import jax.numpy as jnp
+        span = self.phases.span
         p = bits.shape[0]
-        pp = -(-p // 16) * 16                     # pad P: bound recompiles
-        if pp != p:
-            bits = np.concatenate(
-                [bits, np.zeros((pp - p, bits.shape[1]), dtype=bits.dtype)])
-        out = self._kernel(jnp.asarray(bits), *self._tables)
+        with span("pop.labels.launch"):
+            pp = -(-p // 16) * 16                 # pad P: bound recompiles
+            if pp != p:
+                bits = np.concatenate(
+                    [bits, np.zeros((pp - p, bits.shape[1]),
+                                    dtype=bits.dtype)])
+            out = self._kernel(jnp.asarray(bits), *self._tables)
         if self.device is None:
             self.device = next(iter(out.devices()))
-        lf = np.asarray(out)[:p].astype(_I64).ravel()
-        rowbase = self.t.grids(p)[0]
-        if not np.array_equal(lf, lf.take(rowbase + lf)):
-            raise RuntimeError(
-                "jax label kernel returned labels that are not a fixpoint "
-                f"(graph with n={self.t.n}, P={p})")
+        with span("pop.labels.wait"):
+            host = np.asarray(out)
+        with span("pop.labels.check"):
+            lf = host[:p].astype(_I64).ravel()
+            rowbase = self.t.grids(p)[0]
+            if not np.array_equal(lf, lf.take(rowbase + lf)):
+                raise RuntimeError(
+                    "jax label kernel returned labels that are not a "
+                    f"fixpoint (graph with n={self.t.n}, P={p})")
         return lf

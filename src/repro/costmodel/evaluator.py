@@ -43,7 +43,7 @@ batching machinery.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional, Sequence, Union
+from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 from repro.core.fusion import FusionState, iter_bits
 from repro.core.graph import LayerGraph
@@ -52,7 +52,7 @@ from repro.costmodel.base import (CostBreakdown, CostModel, GroupKey,
                                   GroupTotals)
 from repro.costmodel.default import DefaultCostModel
 from repro.costmodel.energy import DEFAULT_ENERGY, EnergyModel
-from repro.obs import clock
+from repro.obs import Phases, clock
 
 try:                                     # numpy-backed population engine
     from repro.core.population import (MIN_BATCH, PopulationEvaluator,
@@ -293,8 +293,12 @@ class Evaluator:
         if not _HAVE_POP:
             raise RuntimeError("population engine requires numpy")
         if self._pop is None:
-            self._ensure_base()
-            self._pop = PopulationEvaluator(self, backend)
+            # pop.build: the layerwise baseline, the static tables and (jax)
+            # their device_put, timed among the engine's own phases
+            phases = Phases()
+            with phases.span("pop.build"):
+                self._ensure_base()
+                self._pop = PopulationEvaluator(self, backend, phases)
         return self._pop
 
     def _ensure_base(self) -> tuple:
@@ -399,12 +403,13 @@ class Evaluator:
         out = self.costmodel.batch(keys)
         return None if any(bd is None for bd in out) else out
 
-    def cache_stats(self) -> Dict[str, float]:
+    def cache_stats(self) -> Dict[str, Any]:
         """Cache-effectiveness counters.  ``group_hit_rate`` covers explicit
         group-cost lookups only; ``batch_evals_per_sec`` is the headline
         throughput of the array-native population engine (states scored per
         second of in-engine time; 0.0 when every batch took the scalar
-        fallback)."""
+        fallback); ``phases`` is the engine's ``pop.*`` spans
+        (:meth:`PopulationEvaluator.stats`), empty without the engine."""
         touches = self.group_hits + self.group_misses
         stats = {
             "unique_groups": len(self._group_cache),
@@ -418,13 +423,15 @@ class Evaluator:
             "pop_batches": 0,
             "batch_time_s": 0.0,
             "batch_evals_per_sec": 0.0,
+            "phases": {},
         }
         if self._pop is not None:
             ps = self._pop.stats()
             stats.update(
                 pop_backend=ps["backend"], pop_batches=ps["batches"],
                 batch_time_s=ps["batch_time_s"],
-                batch_evals_per_sec=ps["batch_evals_per_sec"])
+                batch_evals_per_sec=ps["batch_evals_per_sec"],
+                phases=ps["phases"])
         return stats
 
     # ---- internals ------------------------------------------------------------------

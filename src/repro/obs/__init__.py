@@ -6,9 +6,13 @@ layer, and the verifiers:
 * :class:`MetricRegistry` — labeled counters / gauges / histograms
   (:mod:`repro.obs.metrics`);
 * :class:`Tracer` — span-scoped, schema-versioned JSONL events
-  (``search`` -> ``generation`` -> ``batch_eval`` -> ``costmodel`` span
-  nesting plus ``island.migration`` / ``serve.job`` / ``verify.*`` points;
+  (``search`` -> ``generation`` -> ``batch_eval`` span nesting plus
+  ``island.migration`` / ``serve.job`` / ``verify.*`` points;
   :mod:`repro.obs.trace`);
+* :class:`Phases` — always-on calls and seconds per named hot-path phase
+  (``pop.*``, ``ga.*``, ``session.*``), written into the JAX profiler's
+  trace as ``TraceAnnotation`` spans while a profiler session records
+  (:mod:`repro.obs.phase`);
 * :class:`TelemetryCollector` — the hook surface instrumented layers call
   (:mod:`repro.obs.collect`);
 * :mod:`repro.obs.clock` — the engine's single wall-clock seam (enforced
@@ -33,6 +37,7 @@ from repro.obs.collect import (SUMMARY_SCHEMA, TRACE_ENV, TelemetryCollector,
                                trace_path_from_env)
 from repro.obs.metrics import (NULL_REGISTRY, Counter, Gauge, Histogram,
                                MetricRegistry, NullRegistry)
+from repro.obs.phase import Phases, merge_phases
 from repro.obs.trace import (NULL_TRACER, SCHEMA_VERSION, NullTracer, Tracer,
                              validate_event)
 
@@ -44,4 +49,5 @@ __all__ = [
     "validate_event",
     "TelemetryCollector", "TRACE_ENV", "SUMMARY_SCHEMA",
     "trace_path_from_env",
+    "Phases", "merge_phases",
 ]

@@ -2,7 +2,7 @@
 
 Every instrumented module (``search.session``, ``search.artifact``,
 ``serve.scheduler``, ``costmodel.evaluator``, ``core.population``, and
-``repro.obs`` itself) reads time through these three functions instead of
+``repro.obs`` itself) reads time through these three callables instead of
 calling ``time.*`` directly.  The determinism linter's ``clock-seam`` rule
 (``[tool.repro.lint.clock_seam]`` in pyproject.toml) enforces the routing,
 so the wall-clock allowlist names exactly one file — this one — and every
@@ -26,6 +26,7 @@ def now() -> float:
     return _time.time()
 
 
-def perf_counter() -> float:
-    """Monotonic high-resolution timer, for span durations and throughput."""
-    return _time.perf_counter()
+#: Monotonic high-resolution timer, for span durations and throughput: the
+#: C function itself, with no Python frame around it (phase spans read it
+#: twice each on the hot path).
+perf_counter = _time.perf_counter

@@ -8,7 +8,8 @@ recording hooks each layer calls:
 * ``record_batch`` / ``note_group_costed`` — ``costmodel.Evaluator``, once
   per *batch* (never per offspring): states scored, novel genomes, invalid
   (schedulability-rejected) count, engine backend, novel groups costed.
-  Emits nested ``batch_eval``/``costmodel`` spans.
+  Emits one ``batch_eval`` span; its ``cost_s`` attribute is the batch's
+  summed novel-group costing time.
 * ``begin_search`` / ``on_step`` / ``end_search`` — ``SearchSession``:
   per-generation convergence records (best/mean/std, rejection rate,
   group-cache hit rate) drained from the batch window at each observer
@@ -72,7 +73,6 @@ class TelemetryCollector:
         self._c_invalid = reg.counter("eval.invalid")
         self._c_novel_groups = reg.counter("costmodel.novel_groups")
         self._h_batch_size = reg.histogram("eval.batch_size")
-        self._h_batch_s = reg.histogram("eval.batch_s")
         self._engine_counters: Dict[str, Counter] = {}
         # batch window accumulators, drained at each observer tick
         self._w_states = 0
@@ -138,7 +138,6 @@ class TelemetryCollector:
         self._c_invalid.inc(inv)
         self._c_novel_groups.inc(novel_groups)
         self._h_batch_size.observe(n_states)
-        self._h_batch_s.observe(dur_s)
         ec = self._engine_counters.get(engine)
         if ec is None:
             ec = self.registry.counter("eval.batches_by_engine",
@@ -148,16 +147,11 @@ class TelemetryCollector:
         cost_s, self._cost_s = self._cost_s, 0.0
         tr = self.tracer
         if tr.enabled:
-            bid = tr.alloc_id()
-            parent = tr.current()
-            if novel_groups:
-                tr.emit_span("costmodel", t0=t0, dur_s=cost_s, parent=bid,
-                             attrs={"novel_groups": novel_groups})
-            tr.emit_span("batch_eval", t0=t0, dur_s=dur_s, span_id=bid,
-                         parent=parent,
+            tr.emit_span("batch_eval", t0=t0, dur_s=dur_s,
                          attrs={"n_states": n_states, "n_unique": n_unique,
                                 "invalid": inv,
                                 "novel_groups": novel_groups,
+                                "cost_s": _r6(cost_s),
                                 "engine": engine})
 
     # ---- search session hooks ---------------------------------------------------

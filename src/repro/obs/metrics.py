@@ -29,17 +29,17 @@ def series_name(name: str, labels: Tuple[Tuple[str, str], ...]) -> str:
 
 
 class Counter:
-    """Monotonically increasing event count."""
+    """Monotonically increasing event count, or sum (seconds waited)."""
 
     __slots__ = ("value",)
 
     def __init__(self) -> None:
-        self.value = 0
+        self.value: Union[int, float] = 0
 
-    def inc(self, n: int = 1) -> None:
+    def inc(self, n: Union[int, float] = 1) -> None:
         self.value += n
 
-    def snapshot(self) -> int:
+    def snapshot(self) -> Union[int, float]:
         return self.value
 
 
@@ -151,7 +151,7 @@ class _NullInstrument:
     __slots__ = ()
     value = 0
 
-    def inc(self, n: int = 1) -> None:
+    def inc(self, n: Union[int, float] = 1) -> None:
         pass
 
     def set(self, v: float) -> None:

@@ -45,6 +45,7 @@ from typing import List, Optional, Tuple
 
 from repro.core.ga import GAConfig, GAResult, run_ga_problem
 from repro.core.problem import SearchProblem
+from repro.obs import merge_phases
 
 from repro.search.backends import (GABackend, Observer, SearchBackend,
                                    BackendError)
@@ -179,7 +180,7 @@ def _island_worker(problem: SearchProblem, config: GAConfig,
         res = run_ga_problem(problem, config, observe, migrate=migrate)
         chan.send(("done", problem.encode_genome(res.best_state),
                    res.best_fitness, res.history, res.evaluations,
-                   res.offspring_evaluated))
+                   res.offspring_evaluated, res.phases))
     except BaseException as e:                      # surface, don't deadlock
         chan.send(("error", f"{type(e).__name__}: {e}"))
         raise
@@ -359,7 +360,7 @@ class IslandBackend(SearchBackend):
         # islands (ties break toward the lowest island id, so islands=N is
         # never worse than any single member island at the same seed)
         best_i = max(range(n), key=lambda i: results[i][2])
-        _, enc, best_f, history, _evals, _off = results[best_i]
+        enc, best_f = results[best_i][1:3]
         merged_hist = [max(h) for h in zip(*(m[3] for m in results))]
         return GAResult(
             best_state=problem.decode_genome(enc),
@@ -369,4 +370,5 @@ class IslandBackend(SearchBackend):
             # distinguishable without shipping every key home, so this is
             # an upper bound on globally unique genomes
             evaluations=sum(m[4] for m in results),
-            offspring_evaluated=sum(m[5] for m in results))
+            offspring_evaluated=sum(m[5] for m in results),
+            phases=merge_phases(*(m[6] for m in results)))

@@ -19,8 +19,8 @@ from repro.core.problem import FusionProblem
 from repro.costmodel.accelerator import Accelerator
 from repro.costmodel.energy import DEFAULT_ENERGY, EnergyModel
 from repro.costmodel.evaluator import NATIVE_OBJECTIVES, Evaluator
-from repro.obs import (TelemetryCollector, Tracer, clock,
-                       trace_path_from_env)
+from repro.obs import (Phases, TelemetryCollector, Tracer, clock,
+                       merge_phases, trace_path_from_env)
 
 from repro.search.artifact import ScheduleArtifact, make_artifact
 from repro.search.backends import BackendError
@@ -69,67 +69,75 @@ class SearchSession:
                  embed_ir: Optional[bool] = None,
                  trace_path: Optional[str] = None,
                  obs: Optional[TelemetryCollector] = None):
-        self.spec = spec
-        # JSONL span destination (CLI --trace); REPRO_TRACE is the env
-        # fallback, checked at run() so tests can set it per-run
-        self.trace_path = trace_path
-        # externally-owned collector (repro.serve.daemon): the session
-        # attaches it for the run so callers can stream per-generation
-        # records live, but does NOT embed its summary in the artifact
-        # unless the spec itself asks for telemetry — daemon-produced
-        # artifacts stay byte-compatible with direct SearchSession runs
-        self._external_obs = obs
-        self.telemetry: Optional[TelemetryCollector] = None
-        # artifacts for workloads with no registry entry (file: documents,
-        # direct graphs recorded as ir:<fingerprint>) embed the canonical
-        # GraphIR so they stay reproducible anywhere; registry workloads
-        # can opt in (embed_ir=True / CLI --embed-ir)
-        self.embed_ir = bool(embed_ir) if embed_ir is not None else \
-            spec.workload.startswith(("file:", "ir:"))
-        # resolve everything eagerly so bad names fail at session creation,
-        # not generations into a search
-        if "seed" in spec.backend_config or "observer" in spec.backend_config:
-            raise BackendError(
-                "set the seed via SearchSpec.seed (CLI: --seed) and progress "
-                "hooks via run(progress=...), not backend_config")
-        ga_cfg = spec.backend_config.get("ga_config")
-        ga_obj = ga_cfg.get("objective", spec.objective) \
-            if isinstance(ga_cfg, dict) else \
-            getattr(ga_cfg, "objective", spec.objective)
-        if ga_obj != spec.objective:
-            # run_ga_problem never reads GAConfig.objective (the problem
-            # carries the spec's); a divergent value would be silently
-            # ignored, so refuse it instead
-            raise BackendError(
-                f"ga_config objective {ga_obj!r} conflicts with "
-                f"SearchSpec.objective {spec.objective!r}")
-        self.backend = BACKENDS.get(spec.backend)()
-        OBJECTIVES.get(spec.objective)
-        costmodel_factory = build_costmodel(spec.costmodel)
-        self.graph = graph if graph is not None else \
-            build_workload(spec.workload, **spec.workload_kwargs)
-        self.accelerator = accelerator if accelerator is not None else \
-            build_accelerator(spec.accelerator)
-        self.evaluator = Evaluator(self.graph, self.accelerator,
-                                   em or DEFAULT_ENERGY,
-                                   costmodel=costmodel_factory)
-        # static fusion-space analysis (opt-in): frozen genes + regions,
-        # derived independently of the engine (repro.analysis.spacemap)
-        self.spacemap = None
-        if spec.spacemap:
-            from repro.analysis.spacemap import build_spacemap
-            self.spacemap = build_spacemap(self.graph, spec.costmodel,
-                                           spec.accelerator)
-        if spec.objective in NATIVE_OBJECTIVES:
-            self.problem = FusionProblem(self.graph, self.evaluator,
-                                         spec.objective,
-                                         spacemap=self.spacemap)
-        else:
-            self.problem = _CustomObjectiveProblem(self.graph, self.evaluator,
-                                                   spec.objective,
-                                                   spacemap=self.spacemap)
-        self.result = None                 # GAResult after run()
-        self.artifact: Optional[ScheduleArtifact] = None
+        # session.build (this constructor) and session.finish (run() after
+        # the backend) time the work around the search; the artifact's
+        # backend_stats["phases"] merges them with the engine's pop.* and
+        # the GA run's ga.* spans
+        self.phases = Phases()
+        with self.phases.span("session.build"):
+            self.spec = spec
+            # JSONL span destination (CLI --trace); REPRO_TRACE is the env
+            # fallback, checked at run() so tests can set it per-run
+            self.trace_path = trace_path
+            # externally-owned collector (repro.serve.daemon): the session
+            # attaches it for the run so callers can stream per-generation
+            # records live, but does NOT embed its summary in the artifact
+            # unless the spec itself asks for telemetry — daemon-produced
+            # artifacts stay byte-compatible with direct SearchSession runs
+            self._external_obs = obs
+            self.telemetry: Optional[TelemetryCollector] = None
+            # artifacts for workloads with no registry entry (file:
+            # documents, direct graphs recorded as ir:<fingerprint>) embed
+            # the canonical GraphIR so they stay reproducible anywhere;
+            # registry workloads can opt in (embed_ir=True / CLI --embed-ir)
+            self.embed_ir = bool(embed_ir) if embed_ir is not None else \
+                spec.workload.startswith(("file:", "ir:"))
+            # resolve everything eagerly so bad names fail at session
+            # creation, not generations into a search
+            if ("seed" in spec.backend_config
+                    or "observer" in spec.backend_config):
+                raise BackendError(
+                    "set the seed via SearchSpec.seed (CLI: --seed) and "
+                    "progress hooks via run(progress=...), not "
+                    "backend_config")
+            ga_cfg = spec.backend_config.get("ga_config")
+            ga_obj = ga_cfg.get("objective", spec.objective) \
+                if isinstance(ga_cfg, dict) else \
+                getattr(ga_cfg, "objective", spec.objective)
+            if ga_obj != spec.objective:
+                # run_ga_problem never reads GAConfig.objective (the problem
+                # carries the spec's); a divergent value would be silently
+                # ignored, so refuse it instead
+                raise BackendError(
+                    f"ga_config objective {ga_obj!r} conflicts with "
+                    f"SearchSpec.objective {spec.objective!r}")
+            self.backend = BACKENDS.get(spec.backend)()
+            OBJECTIVES.get(spec.objective)
+            costmodel_factory = build_costmodel(spec.costmodel)
+            self.graph = graph if graph is not None else \
+                build_workload(spec.workload, **spec.workload_kwargs)
+            self.accelerator = accelerator if accelerator is not None else \
+                build_accelerator(spec.accelerator)
+            self.evaluator = Evaluator(self.graph, self.accelerator,
+                                       em or DEFAULT_ENERGY,
+                                       costmodel=costmodel_factory)
+            # static fusion-space analysis (opt-in): frozen genes + regions,
+            # derived independently of the engine (repro.analysis.spacemap)
+            self.spacemap = None
+            if spec.spacemap:
+                from repro.analysis.spacemap import build_spacemap
+                self.spacemap = build_spacemap(self.graph, spec.costmodel,
+                                               spec.accelerator)
+            if spec.objective in NATIVE_OBJECTIVES:
+                self.problem = FusionProblem(self.graph, self.evaluator,
+                                             spec.objective,
+                                             spacemap=self.spacemap)
+            else:
+                self.problem = _CustomObjectiveProblem(
+                    self.graph, self.evaluator, spec.objective,
+                    spacemap=self.spacemap)
+            self.result = None                 # GAResult after run()
+            self.artifact: Optional[ScheduleArtifact] = None
 
     @classmethod
     def from_objects(cls, graph: LayerGraph, accelerator: Accelerator,
@@ -223,6 +231,19 @@ class SearchSession:
                 self.evaluator.attach_telemetry(None)
                 self.problem.obs = None
         wall_s = clock.perf_counter() - t0
+        with self.phases.span("session.finish"):
+            self.artifact = self._finish(collector, tracer, wall_s)
+        # the artifact's phases include session.finish itself
+        self.artifact.backend_stats["phases"] = merge_phases(
+            self.artifact.backend_stats["phases"], self.result.phases,
+            self.phases.snapshot())
+        return self.artifact
+
+    def _finish(self, collector: Optional[TelemetryCollector],
+                tracer: Optional[Tracer], wall_s: float
+                ) -> ScheduleArtifact:
+        """The exact best cost, its breakdowns, the telemetry summary and
+        the artifact of a finished backend run."""
         best_cost = self.evaluator.evaluate(self.result.best_state)
         assert best_cost is not None, \
             "backend returned an invalid best state"
@@ -238,14 +259,13 @@ class SearchSession:
             # daemon-run artifact is byte-identical to a direct run's
             if self._external_obs is None or self.spec.telemetry:
                 telemetry = collector.summary(stats)
-        self.artifact = make_artifact(
+        return make_artifact(
             self.spec, self.graph, self.result,
             baseline=self.evaluator.layerwise(), best=best_cost,
             wall_s=wall_s, backend_stats=self.evaluator.cache_stats(),
             group_breakdowns=breakdowns, embed_ir=self.embed_ir,
             spacemap=self.spacemap.summary() if self.spacemap else None,
             telemetry=telemetry)
-        return self.artifact
 
     # ---- compatibility ----------------------------------------------------------
     def schedule_result(self):

@@ -13,9 +13,12 @@ API (all JSON)::
                               "priority": 0, "warm_start": false}
                              -> {"id": N, "state": ..., ...}
     GET    /jobs             -> {"jobs": [...]}
-    GET    /jobs/<id>        -> job state + live per-generation convergence
+    GET    /jobs/<id>        -> job state, queue_wait_s and run_s, and live
+                                per-generation convergence
     DELETE /jobs/<id>        -> cancel (cooperative abort when running)
-    GET    /metrics          -> MetricRegistry snapshot + queue/store stats
+    GET    /metrics          -> MetricRegistry snapshot (queue wait:
+                                daemon.queue_wait_s sum and daemon.queue_waits
+                                count) + queue/store stats
     GET    /artifacts/<key>  -> raw stored ScheduleArtifact JSON
     GET    /healthz          -> {"ok": true}
 
@@ -182,6 +185,11 @@ class ScheduleDaemon:
             job = self.queue.next_job()
             if job is None:
                 return                   # queue closed: daemon stopping
+            wait = job.queue_wait_s(clock.perf_counter())
+            if wait is not None:
+                with self._lock:
+                    self.registry.counter("daemon.queue_wait_s").inc(wait)
+                    self.registry.counter("daemon.queue_waits").inc()
             self._run_job(job)
 
     def _run_job(self, job: QueuedJob) -> None:
@@ -190,7 +198,6 @@ class ScheduleDaemon:
         if stop.is_set() and not self._shutdown.is_set():
             self.queue.resolve_cancelled(job.id)
             return
-        t0 = clock.perf_counter()
         try:
             spec = SearchSpec.from_dict(job.spec_dict)
             fp = self._fingerprint(spec)
@@ -226,8 +233,6 @@ class ScheduleDaemon:
             key = self.store.put(artifact)
             self.searches_run += 1
             self.registry.counter("daemon.jobs", outcome="searched").inc()
-            self.registry.histogram("daemon.job_wall_s").observe(
-                clock.perf_counter() - t0)
             self.queue.resolve_done(job.id, "searched", key)
         except JobCancelled:
             if self._shutdown.is_set():
@@ -260,6 +265,9 @@ class ScheduleDaemon:
                  ) -> Dict[str, Any]:
         d = job.to_dict()
         d["deduped"] = job.attached_to is not None
+        now = clock.perf_counter()
+        d["queue_wait_s"] = job.queue_wait_s(now)
+        d["run_s"] = job.run_s(now)
         if progress:
             with self._lock:
                 col = self._collectors.get(job.id)

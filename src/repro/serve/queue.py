@@ -62,10 +62,34 @@ class QueuedJob:
     error: Optional[str] = None
     attached_to: Optional[int] = None  # deduped onto this primary job id
     submitted_unix: int = 0
+    # this process's perf_counter stamps (never journaled): submission, or
+    # re-queue at replay; a worker taking the job; its resolution
+    submitted_s: Optional[float] = None
+    started_s: Optional[float] = None
+    ended_s: Optional[float] = None
 
     @property
     def terminal(self) -> bool:
         return self.state in _TERMINAL
+
+    def queue_wait_s(self, now: float) -> Optional[float]:
+        """Seconds from submission until a worker took the job (still
+        counting while it waits); None for a job resolved without a run
+        of its own (store hit, attached duplicate, cancelled while
+        queued)."""
+        if self.submitted_s is None:
+            return None
+        if self.started_s is not None:
+            return self.started_s - self.submitted_s
+        return None if self.terminal else now - self.submitted_s
+
+    def run_s(self, now: float) -> Optional[float]:
+        """Seconds from a worker taking the job until its resolution
+        (still counting while it runs); None if no worker took it."""
+        if self.started_s is None:
+            return None
+        end = self.ended_s if self.ended_s is not None else now
+        return end - self.started_s
 
     def to_dict(self) -> Dict[str, Any]:
         return {
@@ -182,6 +206,7 @@ class JobQueue:
                 job.state = "queued"
                 continue                 # resolved through its primary
             job.state = "queued"
+            job.submitted_s = clock.perf_counter()
             report.requeued += 1
             self._push(job)
         return report
@@ -210,7 +235,8 @@ class JobQueue:
             job = QueuedJob(id=self._next_id, spec_dict=dict(spec_dict),
                             priority=int(priority),
                             warm_start=bool(warm_start), key=key,
-                            submitted_unix=clock.unix_time())
+                            submitted_unix=clock.unix_time(),
+                            submitted_s=clock.perf_counter())
             self._next_id += 1
             primary = None
             if resolved is None and key is not None:
@@ -224,6 +250,7 @@ class JobQueue:
             if resolved is not None:
                 outcome, rkey = resolved
                 job.state, job.outcome, job.key = "done", outcome, rkey
+                job.ended_s = job.submitted_s
                 self._append("done", id=job.id, outcome=outcome, key=rkey)
             elif job.attached_to is None:
                 self._push(job)
@@ -250,6 +277,7 @@ class JobQueue:
                     if job.state != "queued" or job.attached_to is not None:
                         continue         # cancelled/attached while queued
                     job.state = "running"
+                    job.started_s = clock.perf_counter()
                     self._append("start", id=job.id)
                     return job
                 if self._closed:
@@ -263,9 +291,11 @@ class JobQueue:
         with self._cond:
             job = self.jobs[job_id]
             job.state, job.outcome, job.key = "done", outcome, key
+            job.ended_s = clock.perf_counter()
             self._append("done", id=job_id, outcome=outcome, key=key)
             for dup in self._attached(job_id):
                 dup.state, dup.outcome, dup.key = "done", "cache_hit", key
+                dup.ended_s = job.ended_s
                 self._append("done", id=dup.id, outcome="cache_hit", key=key)
 
     def resolve_failed(self, job_id: int, error: str) -> None:
@@ -274,9 +304,11 @@ class JobQueue:
         with self._cond:
             job = self.jobs[job_id]
             job.state, job.error = "failed", str(error)
+            job.ended_s = clock.perf_counter()
             self._append("failed", id=job_id, error=job.error)
             for dup in self._attached(job_id):
                 dup.state, dup.error = "failed", job.error
+                dup.ended_s = job.ended_s
                 self._append("failed", id=dup.id, error=job.error)
 
     def resolve_cancelled(self, job_id: int) -> None:
@@ -285,6 +317,7 @@ class JobQueue:
         with self._cond:
             job = self.jobs[job_id]
             job.state = "cancelled"
+            job.ended_s = clock.perf_counter()
             self._append("cancelled", id=job_id)
             for dup in self._attached(job_id):
                 dup.attached_to = None
@@ -311,6 +344,7 @@ class JobQueue:
             # (heap entry is skipped lazily by next_job)
             job.state = "cancelled"
             job.attached_to = None
+            job.ended_s = clock.perf_counter()
             self._append("cancelled", id=job_id)
             return "cancelled"
 

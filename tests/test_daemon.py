@@ -217,6 +217,27 @@ def test_daemon_store_hit_serves_with_zero_new_evaluations(daemon):
     assert svc.store_hits == 1
 
 
+def test_daemon_reports_queue_wait_and_run_time(daemon):
+    """One worker: the second job waits for the first one's run; the
+    views and the /metrics counters read the same perf_counter stamps."""
+    svc, base = daemon
+    ids = [_post(base, "/jobs", {"spec": fast_spec(seed=s).to_dict()})["id"]
+           for s in (0, 1)]
+    first, second = [_wait(base, i) for i in ids]
+    for j in (first, second):
+        assert j["outcome"] == "searched"
+        assert j["queue_wait_s"] >= 0.0 and j["run_s"] > 0.0
+    assert second["queue_wait_s"] > 0.0
+    hit = _post(base, "/jobs", {"spec": fast_spec(seed=0).to_dict()})
+    assert hit["outcome"] == "cache_hit"
+    assert hit["queue_wait_s"] is None and hit["run_s"] is None
+    m = _get(base, "/metrics")["metrics"]
+    assert m["counters"]["daemon.queue_waits"] == 2
+    assert m["counters"]["daemon.queue_wait_s"] == pytest.approx(
+        first["queue_wait_s"] + second["queue_wait_s"])
+    assert "daemon.job_wall_s" not in m["histograms"]
+
+
 def test_daemon_404s(daemon):
     svc, base = daemon
     for path in ("/jobs/999", "/artifacts/" + "0" * 64, "/nope"):
@@ -332,13 +353,18 @@ def test_daemon_default_results_bit_identical_to_direct_session(tmp_path):
         svc.stop()
     # same fixed-seed trajectory, same store key, byte-identical payload
     # minus wall-clock provenance (wall_s, created_unix, and the timing
-    # rates inside backend_stats are the only fields a clock feeds)
+    # rates and phase seconds inside backend_stats are the only fields a
+    # clock feeds); each phase ran as many times on both paths
     assert done["key"] == artifact_key(direct.graph_fingerprint, spec)
     a, b = direct.to_dict(), via_daemon.to_dict()
     for d in (a, b):
         d.pop("wall_s"), d.pop("created_unix")
         for k in ("batch_time_s", "batch_evals_per_sec"):
             d["backend_stats"].pop(k, None)
+        d["backend_stats"]["phases"] = {
+            name: v["calls"]
+            for name, v in d["backend_stats"]["phases"].items()}
+    assert a["backend_stats"]["phases"]["session.build"] == 1
     assert a == b
 
 

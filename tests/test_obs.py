@@ -324,6 +324,7 @@ def test_collector_span_scaffolding_counts_generations():
     col = TelemetryCollector(tracer=Tracer(stream=buf))
     col.bind_evaluator(FakeEvaluator())
     col.begin_search({"workload": "w", "seed": 0})
+    col.note_group_costed(0.25)
     col.record_batch(2, 2, [1.0, 1.5], "scalar", 0.0, 0.01, 1)
     col.on_step(0, best=1.5, evals=2, offspring=2)
     col.record_batch(2, 1, [1.5], "scalar", 0.0, 0.01, 0)
@@ -343,9 +344,11 @@ def test_collector_span_scaffolding_counts_generations():
     assert all(g["parent"] == search["id"] for g in by_name["generation"])
     gen_ids = {g["id"] for g in by_name["generation"]}
     assert all(b["parent"] in gen_ids for b in by_name["batch_eval"])
-    # novel-group costing window nests under its batch span
-    cost = by_name["costmodel"][0]
-    assert cost["parent"] == by_name["batch_eval"][0]["id"]
+    # novel-group costing time is an attribute of its batch span, drained
+    # per batch (no span of its own: its start was never known)
+    assert "costmodel" not in by_name
+    assert [b["attrs"]["cost_s"] for b in by_name["batch_eval"]] == [0.25,
+                                                                   0.0]
     assert by_name["metrics.snapshot"][0]["parent"] == search["id"]
 
 
