@@ -51,11 +51,15 @@ integer arrays:
 
 Backends: ``numpy`` (default) and ``jax`` (opt-in via
 ``REPRO_POP_ENGINE=jax`` or ``PopulationEvaluator(backend="jax")``), which
-runs the label-propagation inner loop as a jitted kernel
-(:func:`label_kernel`) on JAX's default device and keeps the cost gathers in
-numpy — labels are integers, so the jax path stays bit-identical.  A jax
-engine that cannot run raises; it never falls back to numpy.  Its compiled
-programs go to JAX's persistent compilation cache
+runs the label pass as a jitted kernel (:func:`label_kernel`) on JAX's
+default device and keeps the cost gathers in numpy.  The kernel is the same
+algorithm as the numpy labels, with a fixed round count, written without a
+single data-dependent gather or scatter: a TPU runs those one element at a
+time, so each becomes a dense int32 compare against a one-hot mask and a
+``max``/``min`` reduction, which its vector unit runs in parallel
+(:func:`_labels_jax`).  Labels are integers, so the jax path stays
+bit-identical.  A jax engine that cannot run raises; it never falls back to
+numpy.  Its compiled programs go to JAX's persistent compilation cache
 (:func:`enable_compile_cache`).  Set ``REPRO_POP_ENGINE=off`` to force the
 per-state scalar path.
 
@@ -675,38 +679,69 @@ def label_tables(t: StaticTables) -> tuple:
 def _labels_jax(bits, ar, chain_nodes, chain_eids, xu, xv, extra_eids):
     """Label propagation for a ``(P, m)`` 0/1 genome matrix: chain-run
     labels, then a fixed number of hook-to-min / pointer-jump rounds over
-    the extra edges.  Every shape, and so the compiled program, follows from
-    the argument shapes alone."""
+    the extra edges, then a final jump.  Every shape, and so the compiled
+    program, follows from the argument shapes alone.
+
+    No step indexes by data.  A TPU runs a gather or scatter with data
+    indices one element at a time (~20 ns each on a v5e), so every index
+    here is an int32 compare against a one-hot mask built from the tables,
+    and every pick or scatter a ``max``/``min``/``any`` over the selected
+    entries, which the vector unit runs in parallel.  Labels are held
+    node-major, ``(n, P)``, so the genomes lie along the vector lanes and
+    the large reductions run over a leading axis:
+
+    * column picks ``bits[:, eids]``: ``(k, m)`` masks, ``any`` over ``m``;
+    * run breaks: node ``v`` starts a run unless it has a chain edge into
+      it and every such edge is fused (a ``(C, n)`` mask);
+    * endpoint labels ``lab[xu]``: ``(E, n)`` masks, ``max`` over ``n``;
+    * hook: both scatter-mins of a round as one ``(E, n, P)`` select and
+      ``min`` over the edges, exact because both scatter the same minimum;
+    * pointer jump ``lab[lab[v, p], p]``: a ``(n, n, P)`` select and
+      ``max`` over ``u``, in which exactly one ``u == lab[v, p]`` matches.
+
+    Integers throughout, never a float matmul: the labels must come out
+    exact.  The largest intermediate, ``n x n x P`` int32, is fused into
+    its reduction by XLA and never stored."""
     import jax
     import jax.numpy as jnp
 
-    p = bits.shape[0]
     n = ar.shape[0]
     rounds = int(np.ceil(np.log2(max(n, 2)))) + 2
-    newrun = jnp.ones((p, n), dtype=bool)
-    newrun = newrun.at[:, chain_nodes + 1].set(
-        ~bits[:, chain_eids].astype(bool))
-    lab = jax.lax.cummax(jnp.where(newrun, ar, 0), axis=1)
+    on = (bits != 0).T                                   # (m, P)
+    col = jnp.arange(bits.shape[1], dtype=jnp.int32)
+    node = ar[:, None]                                   # (n, 1)
+
+    def pick(eids):                                      # bits[:, eids].T
+        hit = eids[:, None] == col                       # (k, m)
+        return jnp.any(hit[:, :, None] & on, axis=1)
+
+    def at(lab, hit):                                    # lab[nodes]
+        return jnp.max(jnp.where(hit[:, :, None], lab, -1), axis=1)
+
+    def jump(lab):                                       # lab[lab[v, p], p]
+        hit = lab == node[:, :, None]                    # (u, v, P)
+        return jnp.max(jnp.where(hit, lab[:, None, :], -1), axis=0)
+
+    into = (chain_nodes + 1)[:, None] == ar              # (C, n)
+    broken = jnp.any(into[:, :, None] & ~pick(chain_eids)[:, None, :],
+                     axis=0)
+    newrun = broken | ~jnp.any(into, axis=0)[:, None]
+    lab = jax.lax.cummax(jnp.where(newrun, node, 0), axis=0)
     if extra_eids.shape[0]:
-        fused = bits[:, extra_eids].astype(bool)
-        rows = jnp.arange(p)[:, None]
+        fused = pick(extra_eids)[:, None, :]             # (E, 1, P)
+        hu = xu[:, None] == ar                           # (E, n); XLA does
+        hv = xv[:, None] == ar                           # not hoist these
+        big = jnp.iinfo(jnp.int32).max
 
         def body(lab, _):
-            a = jnp.take_along_axis(lab, jnp.broadcast_to(xu, fused.shape),
-                                    axis=1)
-            b = jnp.take_along_axis(lab, jnp.broadcast_to(xv, fused.shape),
-                                    axis=1)
-            mn = jnp.minimum(a, b)
-            big = jnp.iinfo(lab.dtype).max
-            lab = lab.at[rows, jnp.where(fused, a, 0)].min(
-                jnp.where(fused, mn, big))
-            lab = lab.at[rows, jnp.where(fused, b, 0)].min(
-                jnp.where(fused, mn, big))
-            lab = jnp.take_along_axis(lab, lab, axis=1)   # pointer jump
-            return lab, None
+            a = at(lab, hu)[:, None, :]
+            b = at(lab, hv)[:, None, :]
+            hit = fused & ((a == node) | (b == node))    # (E, n, P)
+            hook = jnp.where(hit, jnp.minimum(a, b), big).min(axis=0)
+            return jump(jnp.minimum(lab, hook)), None
 
         lab, _ = jax.lax.scan(body, lab, None, length=rounds)
-    return jnp.take_along_axis(lab, lab, axis=1)
+    return jump(lab).T
 
 
 @functools.cache
@@ -720,9 +755,12 @@ def label_kernel():
 class _JaxLabels:
     """The jax engine's label pass for one graph: tables placed on the
     default device once, P padded to a multiple of 16 to bound recompiles.
-    Integer-only, so results are bit-identical to the numpy path; the
-    fixed round count always reaches the fixpoint on connected hooks, and
-    the host checks that it did (raising, not falling back, if not)."""
+    One kernel call per batch, with no gather or scatter inside: on a TPU
+    v5e the kernel takes tens of microseconds, and the call's cost is
+    mostly launch, transfer and sync.  Integer-only, so results are
+    bit-identical to the numpy path; the fixed round count always reaches
+    the fixpoint on connected hooks, and the host checks that it did
+    (raising, not falling back, if not)."""
 
     def __init__(self, t: StaticTables, phases: Phases):
         try:

@@ -247,3 +247,22 @@ def test_label_kernel_module_name_is_pinned():
     lowered = label_kernel().lower(bits, *label_tables(t))
     assert "module @jit__labels_jax" in lowered.as_text()
     assert lowered.compile().as_text().startswith("HloModule jit__labels_jax")
+
+
+@pytest.mark.parametrize("workload", ["mobilenet_v3", "resnet50", "unet",
+                                      "vgg16"])
+def test_label_kernel_has_no_gather_or_scatter(workload):
+    """A TPU runs a gather or scatter with data indices one element at a
+    time; the label kernel does every pick, hook and pointer jump as dense
+    compares and reductions instead, and must stay that way."""
+    import numpy as np
+
+    from repro.core.population import (StaticTables, label_kernel,
+                                       label_tables)
+    from repro.search.registry import build_workload
+    t = StaticTables(build_workload(workload).compiled())
+    bits = np.zeros((16, t.m), dtype=np.uint8)
+    text = label_kernel().lower(bits, *label_tables(t)).as_text()
+    assert "module @jit__labels_jax" in text
+    assert "stablehlo.scatter" not in text
+    assert "stablehlo.gather" not in text

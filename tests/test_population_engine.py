@@ -278,6 +278,52 @@ def test_jax_backend_labels_bit_identical():
     assert pe_np.stats()["device_platform"] is None
 
 
+def _skip_dag(n: int, seed: int) -> LayerGraph:
+    """Seeded random DAG over ``n`` nodes in topological id order.  Chain
+    edges ``i-1 -> i`` are rare (always ``0 -> 1``); every node takes two
+    skip edges from the three nodes before its predecessor.  Groups then
+    grow mostly through hooks over extra edges, in long chains: numpy's
+    loop needs 5-7 hook rounds at n >= 127, against the kernel's fixed
+    ``ceil(log2 n) + 2`` = 9-11."""
+    rng = random.Random(seed)
+    g = LayerGraph(f"dag{n}")
+    names = []
+    for i in range(n):
+        ins = [names[i - 1]] if i == 1 or (i and rng.random() < 0.1) else []
+        for _ in range(2 if i > 1 else 0):
+            j = names[rng.randrange(max(0, i - 4), i - 1)]
+            if j not in ins:
+                ins.append(j)
+        names.append(g.add(Layer(name=f"l{i}", kind="add", c=1, h=1, w=1,
+                                 m=1, p=1, q=1), ins))
+    return g
+
+
+@pytest.mark.parametrize("fill", ["random", "all_fused", "none_fused"])
+@pytest.mark.parametrize("p", [1, 17, 100])
+@pytest.mark.parametrize("n", [2, 127, 128, 129, 300])
+def test_jax_labels_match_numpy_on_random_dags(n, p, fill):
+    """The jax label kernel (dense compares and reductions, a fixed round
+    count) equals the numpy loop to its fixpoint, bit for bit, across the
+    128-lane tile and at populations that are no multiple of 16."""
+    graph = _skip_dag(n, seed=1000 + n)
+    pe_np = Evaluator(graph, SIMBA).population(backend="numpy")
+    pe_jx = Evaluator(graph, SIMBA).population(backend="jax")
+    m = pe_np.t.m
+    if fill == "random":
+        rng = np.random.default_rng(n * 1000 + p)
+        bits = (rng.random((p, m)) < rng.uniform(0.6, 1.0, (p, 1)))
+    else:
+        bits = np.full((p, m), fill == "all_fused")
+    bits = bits.astype(np.uint8)
+    want = pe_np._labels_np(bits)
+    assert np.array_equal(pe_jx._jax_labels(bits), want)
+    if fill == "none_fused":
+        assert np.array_equal(want, np.tile(np.arange(n), p))
+    if fill == "all_fused":
+        assert not want.any()
+
+
 # ---- the jax engine never falls back to numpy -------------------------------------
 def test_jax_engine_without_jax_raises(monkeypatch):
     import sys

@@ -65,6 +65,28 @@ def test_label_kernel_compiles_at_zoo_sizes(one_chip, workload, n, m):
     assert out.shape == (PADDED_P, n) and out.dtype == jnp.int32
 
 
+
+def test_label_kernel_fuses_its_jump_at_n_1024(one_chip):
+    """The kernel has one path for every graph size.  Its largest
+    intermediate, the pointer jump's ``n x n x P`` int32 select (512 MiB
+    at n = 1024, P = 128), must stay fused into its reduction: the
+    compiled program stores no temporary of that size."""
+    from repro.core.graph import Layer, LayerGraph
+    n, p = 1024, 128
+    g = LayerGraph("chain1024")
+    names = []
+    for i in range(n):
+        ins = [names[i - 1]] if i else []
+        if i >= 16 and i % 8 == 0:
+            ins.append(names[i - 16])             # a skip edge every 8 nodes
+        names.append(g.add(Layer(name=f"l{i}", kind="add"), ins))
+    t = StaticTables(g.compiled())
+    args = [_shape((p, t.m), jnp.uint8, one_chip)]
+    args += [_shape(a.shape, a.dtype, one_chip) for a in label_tables(t)]
+    compiled = label_kernel().lower(*args).compile()
+    assert compiled.out_info.shape == (p, n)
+    assert compiled.memory_analysis().temp_size_in_bytes < n * n * p * 4
+
 def test_flash_attention_compiles_at_qwen2_7b_width(one_chip):
     from repro.kernels import flash_attention
     # qwen2-7b: 28 query heads, 4 kv heads (GQA), head_dim 128, bf16
