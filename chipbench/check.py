@@ -1,7 +1,8 @@
 """The comparison that decides ``correct``.
 
-Every answer due in the window is held against the plain reference
-(``reference.py``), built from the configuration's own data:
+Every answer due in the window is held against the plain reference the
+configuration names (``references/__init__.py``; ``reference.py`` by
+default), built from the configuration's own data:
 
 * each artifact: the spec it answers, the winner's legality, its costs,
   per-group breakdowns and fitness (``best_fitness`` and the engine's last
@@ -29,7 +30,7 @@ from typing import Dict, Iterable, List, Optional
 
 import numpy as np
 
-from chipbench.reference import Graph, Reference, machine_for
+from chipbench import references
 
 #: floats of a cost record compared by relative gap
 _COST_FLOATS = ("energy_pj", "cycles")
@@ -81,21 +82,23 @@ class Tally:
 
 
 class References:
-    """One reference per machine of a configuration."""
+    """One reference per machine of a configuration, from the reference
+    module the configuration names."""
 
     def __init__(self, config: Dict, dtype=float):
         self.config = config
-        self.graph = Graph(config["graph"])
+        self.mod = references.load(config)
+        self.graph = self.mod.Graph(config["graph"])
         self.dtype = dtype
-        self._refs: Dict[str, Reference] = {}
+        self._refs: Dict[str, object] = {}
 
-    def __call__(self, accelerator: str) -> Reference:
+    def __call__(self, accelerator: str):
         ref = self._refs.get(accelerator)
         if ref is None:
-            ref = self._refs[accelerator] = Reference(
-                self.graph, machine_for(accelerator, self.config["machines"],
-                                        self.config["energy_pj"],
-                                        self.dtype))
+            ref = self._refs[accelerator] = self.mod.Reference(
+                self.graph, self.mod.machine_for(
+                    accelerator, self.config["machines"],
+                    self.config["energy_pj"], self.dtype))
         return ref
 
 
@@ -138,7 +141,7 @@ def check_artifact(a: Dict, want_spec: Dict, refs: References, t: Tally,
     return fit
 
 
-def check_samples(samples: Iterable, ref: Reference, t: Tally,
+def check_samples(samples: Iterable, ref, t: Tally,
                   tag: str) -> float:
     """Hold sampled batch scores against the reference; the best
     reference fitness among the sampled genomes."""
@@ -210,7 +213,7 @@ def verdict(t: Tally, limits: Dict, attempted: int) -> bool:
 
 
 # ---- the control ----------------------------------------------------------
-def _answer32(a: Dict, ref32: Reference) -> Dict:
+def _answer32(a: Dict, ref32) -> Dict:
     """An artifact whose numbers the float32 reference computed."""
     a = copy.deepcopy(a)
     mask = int(a["genome_mask"], 16)

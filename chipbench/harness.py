@@ -3,8 +3,9 @@
 A cell is ``<config>.<mix>`` in ``BENCHMARK.json``.  Its parts are found by
 name: ``configs/<config>.json`` (the deployment and its layer table),
 ``mixes/<mix>.json`` (the traffic's parameters and the driver that offers
-it), ``limits/<cell>.json`` (the limits of the numbers compared) and
-``metrics/<metric>.py`` (one reader per per-layer metric).
+it), ``limits/<cell>.json`` (the limits of the numbers compared),
+``metrics/<metric>.py`` (one reader per per-layer metric) and the plain
+reference the configuration names (``references/__init__.py``).
 
 A run is: device check, set-up (start the system, warm up every shape the
 traffic uses through the cell's own entry), the measured window, the drain
@@ -28,8 +29,8 @@ import time
 from pathlib import Path
 from typing import Callable, Dict, List, Optional
 
-from chipbench.traffic import (closed_loop_seeds, open_loop_jobs,
-                               repeat_share)
+from chipbench import references
+from chipbench.traffic import closed_loop_seeds, open_loop_jobs
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -69,10 +70,13 @@ def load_cell(name: str, bench_path: Path = ROOT / "BENCHMARK.json") -> Dict:
 
 def load_parts(cell: Dict, bench: Optional[Dict] = None) -> Dict:
     """A cell's files, found by the names in its entry; its metrics are
-    those of ``bench`` that name it (none without ``bench``)."""
+    those of ``bench`` that name it (none without ``bench``).  A reference
+    the configuration names but nothing provides fails here, before
+    set-up."""
     name = cell["name"]
     config = json.loads((HERE / "configs" / f"{cell['config']}.json")
                         .read_text())
+    references.load(config)
     mix = json.loads((HERE / "mixes" / f"{cell['traffic']}.json").read_text())
     limits = json.loads((HERE / "limits" / f"{name}.json").read_text())
     bench = bench or {"per_layer": [], "end_to_end": []}
@@ -378,6 +382,12 @@ def _http(base_host: str, port: int, method: str, path: str,
         conn.close()
 
 
+def _labelled(counters: Dict[str, float], prefix: str) -> Dict[str, float]:
+    """The counters named ``prefix<label>}``, by label."""
+    return {k[len(prefix):-1]: v for k, v in counters.items()
+            if k.startswith(prefix)}
+
+
 class DaemonDriver:
     """An in-process ``ScheduleDaemon`` on a loopback port, fed by a load
     generator in a child process that never imports jax."""
@@ -394,17 +404,8 @@ class DaemonDriver:
     def get(self, path: str) -> Dict:
         return _http(self.svc.host, self.svc.port, "GET", path)
 
-    def _outcomes(self) -> Dict[str, int]:
-        counters = self.get("/metrics")["metrics"]["counters"]
-        pre = "daemon.jobs{outcome="
-        return {k[len(pre):-1]: v for k, v in counters.items()
-                if k.startswith(pre)}
-
-    def _engines(self) -> Dict[str, int]:
-        counters = self.get("/metrics")["metrics"]["counters"]
-        pre = "eval.batches_by_engine{engine="
-        return {k[len(pre):-1]: v for k, v in counters.items()
-                if k.startswith(pre)}
+    def _counters(self) -> Dict[str, float]:
+        return self.get("/metrics")["metrics"]["counters"]
 
     def setup(self) -> None:
         from repro.serve import ScheduleDaemon
@@ -443,8 +444,7 @@ class DaemonDriver:
         for j in jobs:
             j["spec"] = job_spec(self.config, self.mix, j["accelerator"],
                               j["seed"])
-        before = self._outcomes()
-        engines0 = self._engines()
+        before = self._counters()
         t0 = now() + 0.05
         self.child.stdin.write(json.dumps({
             "t0": t0, "jobs": [{"t": j["t"], "spec": j["spec"]}
@@ -469,20 +469,20 @@ class DaemonDriver:
         self.child.wait(timeout=60)
         self.child = None
         result = json.loads(out.strip().splitlines()[-1])
-        after = self._outcomes()
-        outcomes = {k: after.get(k, 0) - before.get(k, 0)
-                    for k in set(after) | set(before)}
-        engines = self._engines()
+        after = self._counters()
+        delta = {k: v - before.get(k, 0) for k, v in after.items()}
         keys = sorted({r["key"] for r in result["jobs"]
                        if r.get("state") == "done" and r.get("key")})
         artifacts = {k: self.get(f"/artifacts/{k}") for k in keys}
         for j, r in zip(jobs, result["jobs"]):
             r["spec"] = j["spec"]
         return {"t0": t0, "window_s": seconds, "jobs": result["jobs"],
-                "lateness": result["lateness"], "outcomes": outcomes,
-                "engines": {k: engines.get(k, 0) - engines0.get(k, 0)
-                            for k in engines},
-                "artifacts": artifacts, "repeat_share": repeat_share(jobs),
+                "lateness": result["lateness"],
+                "outcomes": _labelled(delta, "daemon.jobs{outcome="),
+                "engines": _labelled(delta, "eval.batches_by_engine{engine="),
+                "queue_wait": {"seconds": delta.get("daemon.queue_wait_s", 0),
+                               "waits": delta.get("daemon.queue_waits", 0)},
+                "artifacts": artifacts,
                 "compiles_in_window": c1 - c0, "attempted": len(jobs)}
 
     def close(self) -> None:

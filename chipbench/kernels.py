@@ -3,8 +3,8 @@
 The label kernel (``jit__labels_jax``) takes a ``(P, m)`` genome bit matrix
 (one byte per bit), the graph's int32 tables (node ids, chain-edge nodes and
 ids, extra-edge endpoints and ids) and writes ``(P, n)`` int32 labels.  It
-does integer compares and scatter-mins only, so its roofline is the bytes
-bound: no published integer peak of the chip applies to it.
+does dense integer compares and reductions only, so its roofline is the
+bytes bound: no published integer peak of the chip applies to it.
 """
 from __future__ import annotations
 

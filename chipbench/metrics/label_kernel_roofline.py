@@ -1,7 +1,7 @@
 """Label kernel: share of its roofline in the traced sub-window.
 
-The kernel does integer compares and scatter-mins, so its least time is
-its bytes over the chip's HBM bandwidth.  Bytes are what the calls need,
+The kernel does dense integer compares and reductions, so its least time
+is its bytes over the chip's HBM bandwidth.  Bytes are what the calls need,
 from their shapes: the genomes actually scored (unpadded), the graph's
 tables once per call and the labels written.  Calls and genomes come from
 the engine's counters read at the sub-window's two ends; the reading is

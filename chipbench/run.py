@@ -79,11 +79,12 @@ def describe(ctx, window) -> None:
         lat = [j["seen"] - j["t"] for j in window["jobs"]
                if j.get("seen") is not None]
         if lat:
-            log(f"latency: {len(lat)} jobs seen done, "
-                f"{beyond(lat, 0.95)} beyond the p95")
+            log(f"latency: {len(lat)} jobs seen done, p50 "
+                f"{nearest_rank(lat, 0.50)} s, p95 {nearest_rank(lat, 0.95)} "
+                f"s ({beyond(lat, 0.95)} beyond it)")
         log(f"window: {window['attempted']} jobs due, outcomes "
-            f"{json.dumps(window['outcomes'], sort_keys=True)}, schedule "
-            f"repeat share {window['repeat_share']}")
+            f"{json.dumps(window['outcomes'], sort_keys=True)}, queue wait "
+            f"{json.dumps(window['queue_wait'])}")
         log(f"generator: {late['sent']} sent, lateness p50 "
             f"{late['p50_s']} s, max {late['max_s']} s")
         log(f"engine: eval.batches_by_engine "

@@ -2,7 +2,10 @@
 the system sustains without a growing backlog.
 
     python chipbench/sweep.py --workload <cell> --seconds <s> \
-        --seed <n> --rates 1 2 3 ...
+        --seed <n> --rates 0.1 0.2 0.3 ...
+
+Rates are in the mix's ``sweeps_per_s``: design sweeps, each one job per
+machine of the configuration.
 
 One process on the chip; for each rate a fresh daemon, the cell's traffic
 at that rate, and one JSON line: the latency median and tail, and the
@@ -41,7 +44,7 @@ def main(argv=None) -> int:
         harness.log(f"no sweep: {e}")
         return 1
     for rate in args.rates:
-        ctx["mix"]["rate_per_s"] = rate
+        ctx["mix"]["sweeps_per_s"] = rate
         driver = harness.DaemonDriver(ctx)
         try:
             driver.setup()
@@ -56,7 +59,7 @@ def main(argv=None) -> int:
         first = [j["seen"] - j["t"] for j in done[:third]]
         last = [j["seen"] - j["t"] for j in done[-third:]]
         print(json.dumps({
-            "rate_per_s": rate, "jobs": len(jobs), "done": len(done),
+            "sweeps_per_s": rate, "jobs": len(jobs), "done": len(done),
             "p50_s": nearest_rank(lat, 0.5) if lat else None,
             "p95_s": nearest_rank(lat, 0.95) if lat else None,
             "backlog_trend": (nearest_rank(last, 0.5)

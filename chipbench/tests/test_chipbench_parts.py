@@ -10,13 +10,12 @@ import random
 import shutil
 import subprocess
 import sys
-from collections import Counter
 from pathlib import Path
 
 import numpy as np
 import pytest
 
-from chipbench import harness, kernels, peaks, reference, stats, trace, \
+from chipbench import harness, kernels, peaks, references, stats, trace, \
     traffic
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -89,7 +88,7 @@ def test_benchmark_names_and_keys():
 
 
 # ---- traffic --------------------------------------------------------------
-MIX = {"rate_per_s": 3.0, "zipf_s": 1.1, "seed_values": 256}
+MIX = {"sweeps_per_s": 0.25}
 ACCS = [f"eyeriss@act{d:+d}" for d in range(-64, 129, 16)]
 
 
@@ -101,26 +100,27 @@ def test_open_loop_is_a_function_of_the_seed():
 
 
 def test_every_seed_gets_the_same_work():
-    runs = [traffic.open_loop_jobs(MIX, ACCS, s, 51) for s in (1, 2, 3**20)]
-    gaps = [sorted([r[0]["t"]] + [y["t"] - x["t"] for x, y in zip(r, r[1:])])
-            for r in runs]
-    assert len({len(r) for r in runs}) == 1 == len({len(g) for g in gaps})
-    for g in gaps[1:]:
-        assert np.allclose(g, gaps[0], rtol=1e-6, atol=1e-9)
-    shares = {traffic.repeat_share(r) for r in runs}
-    assert len(shares) == 1
-    counts = [sorted(Counter((j["seed"], j["accelerator"])
-                             for j in r).values()) for r in runs]
-    assert counts[0] == counts[1] == counts[2]
+    runs = [traffic.open_loop_jobs(MIX, ACCS, s, 51)
+            for s in (1, 2, 3**20, 2**40 + 3)]
+    for r in runs[1:]:
+        assert [(j["t"], j["accelerator"]) for j in r] == \
+            [(j["t"], j["accelerator"]) for j in runs[0]]
+    assert len({tuple(j["seed"] for j in r) for r in runs}) == len(runs)
     for r in runs:
         assert 0 < r[0]["t"] and r[-1]["t"] < 51
         assert all(x["t"] <= y["t"] for x, y in zip(r, r[1:]))
+        assert len({(j["seed"], j["accelerator"]) for j in r}) == len(r)
 
 
-def test_zipf_keys_favour_low_ranks():
-    keys = traffic.zipf_keys(2000, 1.1, 256, ["a"], random.Random(0))
-    top = Counter(k[0] for k in keys).most_common(1)[0][1]
-    assert 0.18 < top / 2000 < 0.23          # p1 = 1 / H(256, 1.1)
+def test_a_sweep_is_every_machine_once_under_one_seed():
+    jobs = traffic.open_loop_jobs(MIX, ACCS, 2**31 + 77, 51)
+    assert len(jobs) == round(0.25 * 51) * len(ACCS)
+    sweeps = [jobs[i:i + len(ACCS)] for i in range(0, len(jobs), len(ACCS))]
+    for sw in sweeps:
+        assert [j["accelerator"] for j in sw] == ACCS
+        assert len({j["t"] for j in sw}) == len({j["seed"] for j in sw}) == 1
+        assert 0 <= sw[0]["seed"] < traffic.SEARCH_SEEDS
+    assert len({sw[0]["seed"] for sw in sweeps}) == len(sweeps)
 
 
 def test_closed_loop_seeds():
@@ -202,9 +202,14 @@ def test_reduce_recorded_trace():
 
 
 # ---- the plain reference --------------------------------------------------
-@pytest.mark.parametrize("cfg, acc", [("mobilenet_v3-simba", "simba"),
-                                      ("resnet50-eyeriss", "eyeriss@act-64"),
-                                      ("resnet50-eyeriss", "eyeriss@act+128")])
+def _first_and_last_machines():
+    for p in sorted((ROOT / "chipbench" / "configs").glob("*.json")):
+        accs = json.loads(p.read_text())["accelerators"]
+        for acc in dict.fromkeys((accs[0], accs[-1])):
+            yield p.stem, acc
+
+
+@pytest.mark.parametrize("cfg, acc", list(_first_and_last_machines()))
 def test_reference_agrees_with_program(cfg, acc):
     from repro.core.fusion import FusionState
     from repro.costmodel.evaluator import Evaluator
@@ -212,6 +217,7 @@ def test_reference_agrees_with_program(cfg, acc):
     c = json.loads((harness.HERE / "configs" / f"{cfg}.json").read_text())
     graph = build_workload(c["workload"])
     ev = Evaluator(graph, build_accelerator(acc))
+    reference = references.load(c)
     ref = reference.Reference(reference.Graph(c["graph"]),
                               reference.machine_for(acc, c["machines"],
                                                     c["energy_pj"]))
@@ -239,6 +245,7 @@ def test_reference_agrees_with_program(cfg, acc):
 def test_float32_reference_is_off_by_rounding():
     c = json.loads((harness.HERE / "configs" / "mobilenet_v3-simba.json")
                    .read_text())
+    reference = references.load(c)
     g = reference.Graph(c["graph"])
     r64 = reference.Reference(g, reference.machine_for(
         "simba", c["machines"], c["energy_pj"]))
@@ -253,6 +260,7 @@ def test_float32_reference_is_off_by_rounding():
 def test_repartition_keeps_capacity_and_refuses_empty_buffers():
     c = json.loads((harness.HERE / "configs" / "resnet50-eyeriss.json")
                    .read_text())
+    reference = references.load(c)
     m = reference.machine_for("eyeriss@act+64", c["machines"], c["energy_pj"])
     assert (m.act_buf_kib, m.weight_buf_kib) == (192, 448)
     with pytest.raises(ValueError):
