@@ -36,6 +36,7 @@ import random
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Hashable, List, Optional, Sequence, Tuple
 
+from itertools import islice
 from operator import itemgetter
 
 from repro.core.graph import LayerGraph
@@ -147,11 +148,27 @@ def run_ga_problem(problem: SearchProblem, config: GAConfig = GAConfig(),
     (``repro.search.island``); with ``migrate=None`` the loop's behavior is
     bit-for-bit that of earlier revisions.
 
+    Scoring order.  A generation scores its offspring, selects survivors,
+    and scores the top-up mutants that refill the pool.  Making offspring
+    reads only the pool's genomes and its size, never a score, so when
+    ``migrate`` is None and another generation follows, the loop draws that
+    generation's offspring right after the top-up draws (the same RNG draws
+    in the same order) and scores the top-up and those offspring in one
+    call to the problem's batch scorer: one call per generation instead of
+    two.  The offspring's scores are held aside and enter the run-level
+    cache only when their own generation asks for them, so the observer,
+    the budgets and every result see exactly what the two-call order gives;
+    an observer stop drops them.  The last generation, a generation that
+    needs no top-up, and every generation under ``migrate`` (whose hook may
+    replace the pool first) keep the two-call order.
+
     The result's ``phases`` time the loop: ``ga.generation`` (one loop
-    body), ``ga.mutate`` (the mutation and the top-up loops), ``ga.score``
-    (genome keys and the run-level cache, outside the problem's batch
-    scorer), ``ga.select`` (:func:`select_pool`) and ``ga.observe`` (the
-    observer call).
+    body), ``ga.mutate`` (the mutation and the top-up loops), ``ga.ahead``
+    (inside ``ga.mutate``: the next generation's offspring drawn with the
+    top-up; its calls over ``ga.generation``'s are the share of generations
+    scored in one call), ``ga.score`` (genome keys and the run-level cache,
+    outside the problem's batch scorer), ``ga.select`` (:func:`select_pool`)
+    and ``ga.observe`` (the observer call).
     """
     rng = random.Random(config.seed)
     # bound locals for the per-offspring hot path; getrandbits drives an
@@ -161,19 +178,51 @@ def run_ga_problem(problem: SearchProblem, config: GAConfig = GAConfig(),
     pmut = problem.mutate
     pbatch_unique = getattr(problem, "fitness_batch_unique", None)
     fit_cache: Dict[Hashable, float] = {}
+    # scores of the next generation's offspring, scored with this
+    # generation's top-up and not yet asked for
+    held: Dict[Hashable, float] = {}
     offspring_evaluated = 0
     phases = Phases()
     span = phases.span
 
-    def score(states: List) -> List[float]:
-        """Fitness per genome, via the run-level cache; novel genomes are
-        scored in one batch so the evaluator can dedupe shared structure.
-        The fresh list is unique by construction, so problems exposing
-        ``fitness_batch_unique`` skip their own dedup pass."""
+    def breed(parents: List) -> List:
+        """One generation's offspring of the pool's genomes ``parents``."""
+        offspring: List = []
+        npool = len(parents)
+        kbits = npool.bit_length()
+        for _ in range(config.mutations_per_gen):
+            r = getrandbits(kbits)
+            while r >= npool:
+                r = getrandbits(kbits)
+            parent = parents[r]
+            if config.crossover_rate \
+                    and rng.random() < config.crossover_rate \
+                    and npool > 1:
+                other = parents[rng.randrange(npool)]
+                parent = problem.crossover(parent, other, rng)
+            offspring.append(pmut(parent, rng))
+        return offspring
+
+    def score(states: List, ahead: Sequence = ()) -> List[float]:
+        """Fitness per genome of ``states``, via the run-level cache; novel
+        genomes are scored in one batch so the evaluator can dedupe shared
+        structure.  The novel genomes of ``ahead`` (the next generation's
+        offspring) ride in the same batch, and their scores are held until
+        that generation's own call takes them.  The fresh list is unique by
+        construction, so problems exposing ``fitness_batch_unique`` skip
+        their own dedup pass."""
         with span("ga.score"):
             keys = [pkey(s) for s in states]
             fresh: Dict[Hashable, object] = {}
             for k, s in zip(keys, states):
+                if k not in fit_cache and k not in fresh:
+                    if k in held:
+                        fit_cache[k] = held.pop(k)
+                    else:
+                        fresh[k] = s
+            now = len(fresh)
+            for s in ahead:
+                k = pkey(s)
                 if k not in fit_cache and k not in fresh:
                     fresh[k] = s
             vals = list(fresh.values())
@@ -182,7 +231,9 @@ def run_ga_problem(problem: SearchProblem, config: GAConfig = GAConfig(),
                     else problem.fitness_batch(vals))
         with span("ga.score"):
             if vals:
-                fit_cache.update(zip(fresh, fits))
+                scored = zip(fresh, fits)
+                fit_cache.update(islice(scored, now))
+                held.update(scored)
             return [fit_cache[k] for k in keys]
 
     # warm-start seeding (repro.serve.warmstart): extra genomes scored into
@@ -200,24 +251,17 @@ def run_ga_problem(problem: SearchProblem, config: GAConfig = GAConfig(),
             starters.append(seed_genome)
     pool: List[Tuple[float, object]] = list(zip(score(starters), starters))
     history: List[float] = []
+    # this generation's offspring when the last one drew them ahead
+    ahead: Optional[List] = None
+    last = config.generations - 1
 
     for gen in range(config.generations):
         with span("ga.generation"):
-            with span("ga.mutate"):
-                offspring: List = []
-                npool = len(pool)
-                kbits = npool.bit_length()
-                for _ in range(config.mutations_per_gen):
-                    r = getrandbits(kbits)
-                    while r >= npool:
-                        r = getrandbits(kbits)
-                    parent = pool[r][1]
-                    if config.crossover_rate \
-                            and rng.random() < config.crossover_rate \
-                            and len(pool) > 1:
-                        other = pool[rng.randrange(len(pool))][1]
-                        parent = problem.crossover(parent, other, rng)
-                    offspring.append(pmut(parent, rng))
+            if ahead is None:
+                with span("ga.mutate"):
+                    offspring = breed([s for _, s in pool])
+            else:
+                offspring, ahead = ahead, None
             fits = score(offspring)
             offspring_evaluated += len(offspring)
 
@@ -244,7 +288,10 @@ def run_ga_problem(problem: SearchProblem, config: GAConfig = GAConfig(),
                         while j >= n_surv:
                             j = getrandbits(sbits)
                         topup.append(pmut(pool[i if i < j else j][1], rng))
-                tfits = score(topup)
+                    if migrate is None and gen < last:
+                        with span("ga.ahead"):
+                            ahead = breed([s for _, s in pool] + topup)
+                tfits = score(topup, ahead or ())
                 offspring_evaluated += len(topup)
                 pool.extend(zip(tfits, topup))
             if migrate is not None:
@@ -267,7 +314,6 @@ def run_ga_problem(problem: SearchProblem, config: GAConfig = GAConfig(),
                     history=history, evaluations=len(fit_cache),
                     offspring_evaluated=offspring_evaluated,
                     phases=phases.snapshot())
-
 
 def run_ga(graph: LayerGraph, evaluator, config: GAConfig = GAConfig(),
            observer: Optional[GAObserver] = None) -> GAResult:

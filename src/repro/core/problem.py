@@ -63,7 +63,9 @@ class SearchProblem:
         raise NotImplementedError
 
     def mutate(self, genome: Any, rng: random.Random) -> Any:
-        """One random unit mutation (paper Alg. 1 line 4)."""
+        """One random unit mutation (paper Alg. 1 line 4).  Must not read
+        any genome's score: the GA loop draws a generation's offspring
+        before the pool's newest scores are known."""
         raise NotImplementedError
 
     def fitness(self, genome: Any) -> float:
@@ -81,7 +83,8 @@ class SearchProblem:
         return [self.fitness(g) for g in genomes]
 
     def crossover(self, a: Any, b: Any, rng: random.Random) -> Any:
-        """Uniform crossover (beyond-paper); default: no recombination."""
+        """Uniform crossover (beyond-paper); default: no recombination.
+        Like :meth:`mutate`, must not read any genome's score."""
         return a
 
     def neighbors(self, genome: Any) -> Iterable[Any]:

@@ -21,8 +21,8 @@ POP_CHILDREN = ("pop.unpack", "pop.labels.launch", "pop.labels.wait",
                 "pop.labels.check", "pop.maxmem", "pop.rows", "pop.sched",
                 "pop.cost", "pop.gather")
 SPANS = (("pop.build", "pop.batch") + POP_CHILDREN
-         + ("ga.generation", "ga.mutate", "ga.score", "ga.select",
-            "ga.observe", "session.build", "session.finish"))
+         + ("ga.generation", "ga.mutate", "ga.ahead", "ga.score",
+            "ga.select", "ga.observe", "session.build", "session.finish"))
 
 GENERATIONS = 5
 
